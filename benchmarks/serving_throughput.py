@@ -108,6 +108,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.common import trained_model
 from repro.core import SessionSpec
+from repro.launch.runtime import enable_compile_cache
 from repro.serving import EngineConfig, OverloadPolicy, StreamingEngine
 from repro.serving.engine import _mode_shape
 
@@ -715,6 +716,7 @@ def main() -> None:
     ap.add_argument("--no-paged-demo", action="store_true",
                     help="skip the oversubscribed paged-cache pass")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, params, train_ds, test_ds = trained_model(verbose=True,
                                                    direction="retro")

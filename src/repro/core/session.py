@@ -1059,13 +1059,9 @@ def device_page_plan(specs, blocks, page_size: int, n_pages: int,
     count matters for accounting.
 
     ``shards`` is None (one global free stack, the single-device path —
-    bit-identical to before sharding existed) or ``(n_shards, row_shard,
-    gather)`` with ``row_shard`` a host (n_rows_tab,) array mapping each
-    cache row to its owning data shard and ``gather`` a callable that
-    replicates a lane vector across the mesh before the lane concatenate
-    (group leaves shard their slot axis, and concatenating along a
-    sharded axis must happen on gathered copies — see
-    ``StreamingEngine._repl``). Sharded allocation is SEGMENT-LOCAL: shard
+    bit-identical to before sharding existed) or ``(n_shards, row_shard)``
+    with ``row_shard`` a host (n_rows_tab,) array mapping each cache row
+    to its owning data shard. Sharded allocation is SEGMENT-LOCAL: shard
     ``s`` owns the contiguous pages ``[s * pps, (s + 1) * pps)`` and a
     lane draws from its row's shard stack only, so one shard's burst can
     never consume another shard's pool. Exhaustion is still all-or-nothing
@@ -1073,12 +1069,6 @@ def device_page_plan(specs, blocks, page_size: int, n_pages: int,
     preempts a victim inside the overflowing shard and replays, keeping
     the deterministic preempt-and-replay contract per shard."""
     ps, P = int(page_size), int(n_pages)
-    gather = None
-
-    def _cat(parts):
-        """Lane concat; on a mesh, on gathered copies (see docstring)."""
-        return jnp.concatenate(
-            [gather(p) for p in parts] if gather is not None else parts)
 
     leaves, _, idx = paged_cache_entries(gstate.cache)
     bt = leaves[idx[0]].block_tables[0]
@@ -1092,8 +1082,7 @@ def device_page_plan(specs, blocks, page_size: int, n_pages: int,
             jnp.where(free, rank, P)].set(jnp.arange(P, dtype=jnp.int32),
                                           mode="drop")
     else:
-        n_shards, row_shard, *rest = shards
-        gather = rest[0] if rest else None
+        n_shards, row_shard = shards
         n_shards = int(n_shards)
         if P % n_shards:
             raise ValueError(f"n_pages={P} must divide across "
@@ -1132,12 +1121,12 @@ def device_page_plan(specs, blocks, page_size: int, n_pages: int,
         lane_pos.append(jnp.broadcast_to(pos_r[:, None], (nR, W)).reshape(-1))
         lane_w0.append(jnp.broadcast_to(w[None, :] == 0, (nR, W)).reshape(-1))
         lane_gi.append(jnp.full((nR * W,), gi, jnp.int32))
-    r = _cat(lane_r)
-    jb = _cat(lane_j)
-    valid = _cat(lane_valid)
-    posl = _cat(lane_pos)
-    w0 = _cat(lane_w0)
-    gsel = _cat(lane_gi)
+    r = jnp.concatenate(lane_r)
+    jb = jnp.concatenate(lane_j)
+    valid = jnp.concatenate(lane_valid)
+    posl = jnp.concatenate(lane_pos)
+    w0 = jnp.concatenate(lane_w0)
+    gsel = jnp.concatenate(lane_gi)
 
     cur = jnp.where(valid, bt[r, jnp.clip(jb, 0, n_blocks - 1)], -1)
     vc = valid & (cur >= 0)
@@ -1171,9 +1160,9 @@ def device_page_plan(specs, blocks, page_size: int, n_pages: int,
             pc.append(jnp.zeros((L,), bool))
             pu.append(jnp.full((L,), -1, jnp.int32))
             pg.append(jnp.full((L,), gi, jnp.int32))
-        r, jb = _cat(pr), _cat(pj)
-        need, copy = _cat(pn), _cat(pc)
-        cur, gsel = _cat(pu), _cat(pg)
+        r, jb = jnp.concatenate(pr), jnp.concatenate(pj)
+        need, copy = jnp.concatenate(pn), jnp.concatenate(pc)
+        cur, gsel = jnp.concatenate(pu), jnp.concatenate(pg)
 
     need_by_group = jnp.zeros((len(specs),), jnp.int32).at[gsel].add(
         need.astype(jnp.int32))
@@ -1220,12 +1209,19 @@ def apply_page_plan(cache, plan: DevicePagePlan):
     fresh_dst = jnp.where(plan.need & ~plan.copy, plan.new, P)
     bt_new = leaves[idx[0]].block_tables[0].at[
         rr, plan.blocks].set(plan.new, mode="drop")
+    def copy_pages(pool):
+        # one gather per stacked layer: the single all-layer gather of a
+        # page-sharded pool is refused by the TPU compiler under SPMD
+        # (scoped VMEM overflow in its gather fusion)
+        for layer in range(pool.shape[0]):
+            pool = pool.at[layer, copy_dst].set(pool[layer, copy_src],
+                                                mode="drop")
+        return pool
+
     for i in idx:
         sc = leaves[i]
-        k_pool = sc.k_pool.at[:, copy_dst].set(
-            sc.k_pool[:, copy_src], mode="drop")
-        v_pool = sc.v_pool.at[:, copy_dst].set(
-            sc.v_pool[:, copy_src], mode="drop")
+        k_pool = copy_pages(sc.k_pool)
+        v_pool = copy_pages(sc.v_pool)
         pos = sc.pos.at[:, copy_dst].set(sc.pos[:, copy_src], mode="drop")
         pos = pos.at[:, fresh_dst].set(-1, mode="drop")
         leaves[i] = dataclasses.replace(

@@ -9,6 +9,13 @@ is read exactly once per group, not per head. Grid (B, Kv, S/bk), sequential
 kv dimension with online-softmax scratch, masking on the stored-position
 array (ring-buffer/sliding-window semantics identical to
 models.attention.cached_attention).
+
+Mosaic tiles the last two dims of every block by (8, 128) unless a block
+dim spans the whole array dim. So the position arrays carry a unit middle
+axis — key positions ``(., 1, S)`` ride lanes, query positions
+``(B, T*G, 1)`` ride sublanes, one row per query row of the q tile (the
+wrapper expands T positions to T*G rows) — and every block's last two dims
+are either whole or 128-aligned.
 """
 
 from __future__ import annotations
@@ -21,14 +28,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 _NEG = -1e30
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, G: int, bk: int,
-                   kv_blocks: int, window: int, scale: float):
+                   m_ref, l_ref, acc_ref, *, bk: int, kv_blocks: int,
+                   window: int, scale: float):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -43,11 +48,9 @@ def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    kp = kpos_ref[0]                                      # (bk,)
-    qp = qpos_ref[0]                                      # (T,)
     TG = q.shape[0]
-    qp_rows = jnp.broadcast_to(jnp.repeat(qp, G)[:, None], (TG, bk))
-    kp_b = jnp.broadcast_to(kp[None, :], (TG, bk))
+    kp_b = jnp.broadcast_to(kpos_ref[0], (TG, bk))       # from (1, bk)
+    qp_rows = jnp.broadcast_to(qpos_ref[0], (TG, bk))    # from (TG, 1)
     mask = (kp_b >= 0) & (kp_b <= qp_rows)
     if window > 0:
         mask &= kp_b > qp_rows - window
@@ -69,18 +72,16 @@ def _decode_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref, o_ref,
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def decode_gqa_kernel(q_r, k_r, v_r, k_pos, q_pos, *, window: int = 0,
-                      bk: int = 128, interpret: bool = True):
-    """q_r: (B, Kv, T*G, hd); k_r/v_r: (B, Kv, S, hd); k_pos: (B, S);
-    q_pos: (B, T). S % bk == 0. Returns (B, Kv, T*G, hd)."""
+def decode_gqa_kernel(q_r, k_r, v_r, k_pos, q_rows, *, window: int = 0,
+                      bk: int = 128, interpret: bool):
+    """q_r: (B, Kv, T*G, hd); k_r/v_r: (B, Kv, S, hd); k_pos: (B, 1, S);
+    q_rows: (B, T*G, 1), the position of each query row. S % bk == 0.
+    Returns (B, Kv, T*G, hd)."""
     B, Kv, TG, hd = q_r.shape
     S = k_r.shape[2]
-    T = q_pos.shape[1]
-    G = TG // T
     kv_blocks = S // bk
-    kernel = functools.partial(_decode_kernel, G=G, bk=bk,
-                               kv_blocks=kv_blocks, window=window,
-                               scale=1.0 / math.sqrt(hd))
+    kernel = functools.partial(_decode_kernel, bk=bk, kv_blocks=kv_blocks,
+                               window=window, scale=1.0 / math.sqrt(hd))
     return pl.pallas_call(
         kernel,
         grid=(B, Kv, kv_blocks),
@@ -88,8 +89,8 @@ def decode_gqa_kernel(q_r, k_r, v_r, k_pos, q_pos, *, window: int = 0,
             pl.BlockSpec((1, 1, TG, hd), lambda b, g, ki: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, g, ki: (b, g, ki, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, g, ki: (b, g, ki, 0)),
-            pl.BlockSpec((1, bk), lambda b, g, ki: (b, ki)),
-            pl.BlockSpec((1, T), lambda b, g, ki: (b, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, g, ki: (b, 0, ki)),
+            pl.BlockSpec((1, TG, 1), lambda b, g, ki: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, TG, hd), lambda b, g, ki: (b, g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Kv, TG, hd), q_r.dtype),
@@ -98,10 +99,10 @@ def decode_gqa_kernel(q_r, k_r, v_r, k_pos, q_pos, *, window: int = 0,
             pltpu.VMEM((TG, 1), jnp.float32),
             pltpu.VMEM((TG, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_r, k_r, v_r, k_pos, q_pos)
+    )(q_r, k_r, v_r, k_pos, q_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -109,7 +110,7 @@ def decode_gqa_kernel(q_r, k_r, v_r, k_pos, q_pos, *, window: int = 0,
 
 
 def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, kpos_ref, qpos_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, G: int, ps: int,
+                         o_ref, m_ref, l_ref, acc_ref, *, ps: int,
                          n_blocks: int, window: int, scale: float):
     """One (sequence b, kv-head g, logical block j) grid step. The block
     table rides scalar prefetch: the K/V BlockSpecs DMA page
@@ -131,11 +132,9 @@ def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, kpos_ref, qpos_ref,
                             preferred_element_type=jnp.float32) * scale
 
     mapped = bt_ref[b, j] >= 0                            # unmapped -> page 0
-    kp = kpos_ref[0]                                      # (ps,)
-    qp = qpos_ref[0]                                      # (T,)
     TG = q.shape[0]
-    qp_rows = jnp.broadcast_to(jnp.repeat(qp, G)[:, None], (TG, ps))
-    kp_b = jnp.broadcast_to(kp[None, :], (TG, ps))
+    kp_b = jnp.broadcast_to(kpos_ref[0], (TG, ps))       # from (1, ps)
+    qp_rows = jnp.broadcast_to(qpos_ref[0], (TG, ps))    # from (TG, 1)
     mask = mapped & (kp_b >= 0) & (kp_b <= qp_rows)
     if window > 0:
         mask &= kp_b > qp_rows - window
@@ -158,17 +157,15 @@ def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, kpos_ref, qpos_ref,
 
 
 def paged_decode_gqa_kernel(block_tables, q_r, k_pool, v_pool, pos_pool,
-                            q_pos, *, window: int = 0,
-                            interpret: bool = True):
-    """q_r: (B, Kv, T*G, hd); k/v_pool: (P, Kv, ps, hd); pos_pool: (P, ps);
-    block_tables: (B, n_blocks) int32 page ids (-1 unmapped); q_pos: (B, T).
-    Returns (B, Kv, T*G, hd). One KV tile = one page (bk == page_size)."""
+                            q_rows, *, window: int = 0, interpret: bool):
+    """q_r: (B, Kv, T*G, hd); k/v_pool: (P, Kv, ps, hd); pos_pool:
+    (P, 1, ps); block_tables: (B, n_blocks) int32 page ids (-1 unmapped);
+    q_rows: (B, T*G, 1), the position of each query row. Returns
+    (B, Kv, T*G, hd). One KV tile = one page (bk == page_size)."""
     B, Kv, TG, hd = q_r.shape
     ps = k_pool.shape[2]
     n_blocks = block_tables.shape[1]
-    T = q_pos.shape[1]
-    G = TG // T
-    kernel = functools.partial(_paged_decode_kernel, G=G, ps=ps,
+    kernel = functools.partial(_paged_decode_kernel, ps=ps,
                                n_blocks=n_blocks, window=window,
                                scale=1.0 / math.sqrt(hd))
 
@@ -182,9 +179,9 @@ def paged_decode_gqa_kernel(block_tables, q_r, k_pool, v_pool, pos_pool,
             pl.BlockSpec((1, 1, TG, hd), lambda b, g, j, bt: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, ps, hd), page),
             pl.BlockSpec((1, 1, ps, hd), page),
-            pl.BlockSpec((1, ps),
-                         lambda b, g, j, bt: (jnp.maximum(bt[b, j], 0), 0)),
-            pl.BlockSpec((1, T), lambda b, g, j, bt: (b, 0)),
+            pl.BlockSpec((1, 1, ps),
+                         lambda b, g, j, bt: (jnp.maximum(bt[b, j], 0), 0, 0)),
+            pl.BlockSpec((1, TG, 1), lambda b, g, j, bt: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, TG, hd),
                                lambda b, g, j, bt: (b, g, 0, 0)),
@@ -198,7 +195,7 @@ def paged_decode_gqa_kernel(block_tables, q_r, k_pool, v_pool, pos_pool,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, TG, hd), q_r.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, q_r, k_pool, v_pool, pos_pool, q_pos)
+    )(block_tables, q_r, k_pool, v_pool, pos_pool, q_rows)

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_gqa.kernel import (decode_gqa_kernel,
                                              paged_decode_gqa_kernel)
+from repro.kernels.platform import interpret_mode
 
 
 def _split_heads(q, Kv):
@@ -18,6 +19,12 @@ def _split_heads(q, Kv):
     G = H // Kv
     return q.reshape(B, T, Kv, G, hd).transpose(0, 2, 1, 3, 4).reshape(
         B, Kv, T * G, hd)
+
+
+def _row_positions(q_pos, G):
+    """(B, T) -> (B, T*G, 1): each query row's position, in _split_heads'
+    row order, as a sublane column."""
+    return jnp.repeat(q_pos, G, axis=1)[..., None]
 
 
 def _merge_heads(out, T):
@@ -30,7 +37,7 @@ def _merge_heads(out, T):
 @partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_gqa_attention(q, k_pool, v_pool, pos_pool, block_tables,
                                q_pos, *, window: int = 0,
-                               interpret: bool = True):
+                               interpret: bool | None = None):
     """Paged decode attention: walk the block table, one DMA per mapped
     page — no materialized per-row gather (the XLA fallback builds the
     (B, n_blocks*ps, ...) view; at serving batch sizes that copy dwarfs the
@@ -45,16 +52,17 @@ def paged_decode_gqa_attention(q, k_pool, v_pool, pos_pool, block_tables,
     q_r = _split_heads(q, Kv)
     k_r = k_pool.transpose(0, 2, 1, 3)      # (P, Kv, ps, hd)
     v_r = v_pool.transpose(0, 2, 1, 3)
-    out = paged_decode_gqa_kernel(block_tables.astype(jnp.int32), q_r, k_r,
-                                  v_r, pos_pool, q_pos, window=window,
-                                  interpret=interpret)
+    out = paged_decode_gqa_kernel(
+        block_tables.astype(jnp.int32), q_r, k_r, v_r, pos_pool[:, None],
+        _row_positions(q_pos, H // Kv), window=window,
+        interpret=interpret_mode(interpret))
     return _merge_heads(out, T)
 
 
 @partial(jax.jit, static_argnames=("window", "bk", "interpret"))
 def decode_gqa_attention(q, k_cache, v_cache, k_pos, q_pos, *,
                          window: int = 0, bk: int = 128,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """q: (B, T, H, hd); k/v_cache: (B, S, Kv, hd); k_pos: (B, S) stored
     positions (-1 empty); q_pos: (B, T). Returns (B, T, H, hd)."""
     B, T, H, hd = q.shape
@@ -69,6 +77,7 @@ def decode_gqa_attention(q, k_cache, v_cache, k_pos, q_pos, *,
     q_r = _split_heads(q, Kv)
     k_r = k_cache.transpose(0, 2, 1, 3)
     v_r = v_cache.transpose(0, 2, 1, 3)
-    out = decode_gqa_kernel(q_r, k_r, v_r, k_pos, q_pos, window=window,
-                            bk=bk, interpret=interpret)
+    out = decode_gqa_kernel(q_r, k_r, v_r, k_pos[:, None],
+                            _row_positions(q_pos, H // Kv), window=window,
+                            bk=bk, interpret=interpret_mode(interpret))
     return _merge_heads(out, T)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 _NEG = -1e30
 
 
@@ -59,7 +57,7 @@ def _verify_kernel(logits_ref, drafts_ref, mask_ref, tok_ref, acc_ref,
 
 
 def draft_verify_kernel(logits, drafts, draft_mask, *, bv: int = 512,
-                        interpret: bool = True):
+                        interpret: bool):
     """logits: (N, T, Vp) (vocab padded to bv multiple, true size ``vocab``
     passed implicitly = Vp unless padded by ops); drafts: (N, T-1);
     draft_mask: (N, 1) int32. Returns (tokens (N, T), n_acc (N, 1))."""
@@ -91,7 +89,7 @@ def draft_verify_kernel(logits, drafts, draft_mask, *, bv: int = 512,
             pltpu.VMEM((T, 1), jnp.float32),
             pltpu.VMEM((T, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(logits, drafts, draft_mask)

@@ -8,11 +8,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.draft_verify.kernel import draft_verify_kernel
+from repro.kernels.platform import interpret_mode
 
 
 @partial(jax.jit, static_argnames=("bv", "interpret"))
 def draft_verify(logits, drafts, draft_mask, *, bv: int = 512,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """logits: (N, T, V); drafts: (N, T-1) int32; draft_mask: (N,) bool.
 
     Returns (greedy_tokens (N, T) int32, n_acc (N,) int32) — the fused
@@ -26,5 +27,5 @@ def draft_verify(logits, drafts, draft_mask, *, bv: int = 512,
                          constant_values=-1e30)
     mask_i = draft_mask.astype(jnp.int32)[:, None]
     toks, acc = draft_verify_kernel(logits, drafts, mask_i, bv=bv,
-                                    interpret=interpret)
+                                    interpret=interpret_mode(interpret))
     return toks, acc[:, 0]

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-
 _NEG = -1e30
 
 
@@ -69,7 +67,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
                            bq: int = 128, bk: int = 128,
                            seq_len: int | None = None,
-                           interpret: bool = True):
+                           interpret: bool):
     """q,k,v: (BH, S, hd) with S % bq == S % bk == 0. Returns (BH, S, hd).
     ``seq_len``: true (unpadded) length — keys at or beyond it are masked."""
     BH, S, hd = q.shape
@@ -94,7 +92,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),   # normalizer
             pltpu.VMEM((bq, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -45,18 +45,20 @@ def data_shards(mesh: Mesh) -> int:
 def make_serving_mesh(shape: tuple[int, int] = (2, 2), *,
                       devices=None) -> Mesh:
     """(data, model) mesh for a sharded ``StreamingEngine`` over whatever
-    devices exist — real accelerators in production, forced host-platform
-    devices in tests/CI (set
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` BEFORE the
-    first jax import). Unlike the production mesh this takes any shape
-    that fits the device count, so a (2, 2) mesh exercises real
-    cross-shard paths on one host."""
+    devices exist. Real accelerators are used as they are; forced
+    host-platform devices (``XLA_FLAGS=--xla_force_host_platform_device_
+    count=8`` set BEFORE the first jax import) are for CPU rehearsal and
+    tests only. Unlike the production mesh this takes any shape that fits
+    the device count, so a (2, 2) mesh exercises real cross-shard paths
+    on one host, and ``(1, 1)`` with ``devices=[d]`` pins an engine to
+    device ``d``."""
     n = int(np.prod(shape))
     devices = list(jax.devices() if devices is None else devices)
     if len(devices) < n:
         raise RuntimeError(
             f"serving mesh {tuple(shape)} needs {n} devices, have "
-            f"{len(devices)} — on a host platform set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n} (or more) before "
-            f"importing jax")
+            f"{len(devices)} — real accelerators are used as they are; "
+            f"forced host devices (XLA_FLAGS=--xla_force_host_platform_"
+            f"device_count={n} set before importing jax) are for a CPU "
+            f"rehearsal only")
     return Mesh(np.asarray(devices[:n]).reshape(shape), ("data", "model"))

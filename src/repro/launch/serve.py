@@ -32,6 +32,7 @@ from repro.configs import get_config
 from repro.core import (greedy_decode, prompt_lookup_drafts,
                         speculative_greedy_decode, transformer_handle)
 from repro.launch.mesh import make_serving_mesh
+from repro.launch.runtime import enable_compile_cache
 from repro.models import transformer as tr
 from repro.serving import (EngineConfig, GenerationParams, RequestCancelled,
                            StreamingEngine)
@@ -117,11 +118,13 @@ def main() -> None:
     ap.add_argument("--mesh", type=int, nargs=2, metavar=("DATA", "MODEL"),
                     help="serve the continuous pass on a (data, model) "
                          "device mesh — slots/pages shard over DATA, params "
-                         "over MODEL. Needs DATA*MODEL devices (host "
-                         "platforms: set XLA_FLAGS=--xla_force_host_"
-                         "platform_device_count=N before launch)")
+                         "over MODEL. Needs DATA*MODEL devices: real "
+                         "accelerators are used as they are; for a CPU "
+                         "rehearsal only, set XLA_FLAGS=--xla_force_host_"
+                         "platform_device_count=N before launch")
     ap.add_argument("--no-continuous", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     if cfg.family == "audio":
@@ -161,7 +164,9 @@ def main() -> None:
     print(f"greedy      : {int(g.n_calls)} calls, {t_g:.2f}s")
     print(f"speculative : {int(s.n_calls)} calls, {t_s:.2f}s "
           f"acceptance={float(s.acceptance_rate.mean()):.2f}")
-    print(f"outputs identical: {bool((g.tokens == s.tokens).all())}")
+    identical = bool((g.tokens == s.tokens).all())
+    print(f"outputs identical: {identical}")
+    assert identical, "speculative decoding changed the greedy tokens"
     if not args.no_continuous:
         continuous_demo(params, cfg, prompts, args,
                         expected=np.asarray(s.tokens))

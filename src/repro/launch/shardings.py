@@ -57,10 +57,9 @@ def serving_param_shardings(params, cfg, mesh: Mesh):
     that layout is only safe when the split lands on whole heads: a chunk
     that cuts inside ``head_dim`` reshapes the sharding onto RoPE's
     rotation axis, and that layout splits the rotation pairs across
-    devices (the partitioned concatenate along a sharded axis also
-    miscompiles on host-platform meshes — see ``StreamingEngine._repl``).
-    Q/K/V projections whose head count does not divide the model axis are
-    therefore replicated; everything else follows the rules.
+    devices. Q/K/V projections whose head count does not divide the
+    model axis are therefore replicated; everything else follows the
+    rules.
     """
     pspecs = rules.param_pspecs(params, mesh, fsdp_axes=())
     model = int(dict(mesh.shape).get(rules.MODEL, 1))

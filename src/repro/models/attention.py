@@ -476,8 +476,7 @@ def cached_attention(p: dict, cfg: ModelConfig, x, cache, positions,
             out = paged_decode_gqa_attention(
                 q, cache.k_pool, cache.v_pool, cache.pos,
                 cache.block_tables, positions,
-                window=cfg.sliding_window,
-                interpret=jax.default_backend() != "tpu")
+                window=cfg.sliding_window)
             return dense(p["wo"], out.reshape(B, T, -1)), cache
         k, v, kpos = paged_view(cache)
     else:

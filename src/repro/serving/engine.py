@@ -621,7 +621,7 @@ class StreamingEngine:
                     (self._chunk_rows0(m), pos0, n_valid, C)
                     for m, (_, pos0, n_valid)
                     in zip(self.mode_names, prefill))
-            shards = ((self.n_shards, self._row_shard, self._repl)
+            shards = ((self.n_shards, self._row_shard)
                       if self.n_shards > 1 else None)
             plan = device_page_plan(specs, blocks, ps, n_pages, gstate,
                                     prefill=plan_prefill, shards=shards)
@@ -638,26 +638,10 @@ class StreamingEngine:
             gstate = grouped_step(specs, handle, gstate)
         return gstate, self._make_bundle(gstate, n_out0, plan)
 
-    def _repl(self, x):
-        """All-gather a per-slot row vector before concatenating groups.
-
-        Group leaves shard their slot axis over 'data', and a concatenate
-        along a sharded axis is the one primitive the forced-host SPMD
-        partitioner gets WRONG (jax 0.4.37 lowers it to a partial-sum
-        gather: every element doubles). An explicit replicate constraint
-        first makes the concat a local op on gathered copies, which
-        executes exactly — and the bundle rows are O(n_slots) scalars, so
-        the gather is noise."""
-        if self.mesh is None:
-            return x
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.NamedSharding(self.mesh,
-                                          jax.sharding.PartitionSpec()))
-
     def _slot_counts(self, gstate) -> jnp.ndarray:
         """(n_slots,) committed-token counts on each slot's row 0, global
         slot order (groups are slot-contiguous in declaration order)."""
-        return jnp.concatenate([self._repl(gs.n_out[:, 0])
+        return jnp.concatenate([gs.n_out[:, 0]
                                 for gs in gstate.groups])
 
     def _make_bundle(self, gstate, n_out0, plan) -> dict:
@@ -666,7 +650,7 @@ class StreamingEngine:
         specs = list(self._groups.values())
         maxW = max([s.draft_len + 1 for s in specs if s.kind == "greedy"],
                    default=1)
-        finished = jnp.concatenate([self._repl(gs.finished.all(axis=1))
+        finished = jnp.concatenate([gs.finished.all(axis=1)
                                     for gs in gstate.groups])
         n_out1 = self._slot_counts(gstate)
         n_new = n_out1 - n_out0
@@ -683,7 +667,7 @@ class StreamingEngine:
             else:
                 # beams reorder mid-flight: only terminal reads are truthful
                 d = jnp.zeros((S, maxW), jnp.int32)
-            deltas.append(self._repl(d))
+            deltas.append(d)
             lo += S
         bundle = dict(finished=finished, n_out=n_out1, n_new=n_new,
                       delta=jnp.concatenate(deltas, axis=0))

@@ -72,14 +72,19 @@ class ReplicaClient:
                                                     asyncio.StreamWriter]:
         """Open one proxied request: connect, send the NDJSON request
         object, return the (reader, writer) the caller iterates events
-        from. The in-flight count bumps here and drops in
-        ``stream_closed`` — placement sees the booking immediately, not
-        at the next probe."""
-        reader, writer = await self.connect()
-        writer.write(json.dumps(req, separators=(",", ":")).encode()
-                     + b"\n")
-        await writer.drain()
+        from. The in-flight count bumps BEFORE the first await and drops
+        in ``stream_closed`` (or here, if the open fails) — placement sees
+        the booking immediately, so a burst of concurrent requests spreads
+        instead of all landing on the replica that looked idlest."""
         self.view.inflight += 1
+        try:
+            reader, writer = await self.connect()
+            writer.write(json.dumps(req, separators=(",", ":")).encode()
+                         + b"\n")
+            await writer.drain()
+        except BaseException:
+            self.view.inflight = max(0, self.view.inflight - 1)
+            raise
         self.n_submitted += 1
         return reader, writer
 

@@ -45,7 +45,10 @@ def build_engine(args):
     import jax
     import numpy as np
 
+    from repro.launch.runtime import enable_compile_cache
     from repro.serving import EngineConfig, StreamingEngine
+
+    enable_compile_cache()
 
     ecfg_kw = dict(mode=args.mode, max_new=args.max_new,
                    max_src=args.max_src, n_slots=args.slots,
@@ -131,18 +134,38 @@ def spawn_replicas(n: int, *, extra_args: list[str] | None = None,
     and wait for every readiness handshake. Returns
     ``(procs, addrs)`` — ``addrs`` feeds ``FleetRouter`` directly.
     Kill a replica with ``proc.kill()`` (the drill) or drain it with
-    ``proc.terminate()``; ``stop_replicas`` cleans up the rest."""
-    import repro
+    ``proc.terminate()``; ``stop_replicas`` cleans up the rest. Each
+    child's stderr goes to this process's stderr.
 
-    src_root = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(repro.__file__))))
+    A TPU chip belongs to one process at a time, so this refuses when the
+    calling process already holds the TPU, or when more than one child
+    would reach the same chips. Several replicas on one TPU host run in
+    ONE process instead: one ``FrontDoorServer`` per device, each engine
+    on ``make_serving_mesh((1, 1), devices=[d])`` (``chip_smoke.py
+    --chips 4`` does this)."""
+    from repro.launch.runtime import (checkout_root, host_tpu_chips,
+                                      tpu_backend_live)
+
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (src_root, env.get("PYTHONPATH")) if p)
+        p for p in (os.path.join(checkout_root(), "src"),
+                    env.get("PYTHONPATH")) if p)
+    if tpu_backend_live():
+        raise RuntimeError(
+            "spawn_replicas: this process has initialised the TPU backend "
+            "and holds every chip, so no replica process could reach one; "
+            "spawn before touching JAX, or serve the replicas in this "
+            "process (one FrontDoorServer per device)")
+    platforms = env.get("JAX_PLATFORMS", "")
+    if n > 1 and host_tpu_chips() and (not platforms or "tpu" in platforms):
+        raise RuntimeError(
+            f"spawn_replicas: {n} replica processes would share this "
+            f"host's TPU chips (a chip belongs to one process); serve them "
+            f"in one process instead, one FrontDoorServer per device")
     cmd = [sys.executable, "-u", "-m", "repro.serving.fleet.replica",
            "--port", "0"] + list(extra_args or [])
     procs = [subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
-                              stderr=subprocess.DEVNULL, text=True)
+                              stderr=None, text=True)
              for _ in range(n)]
     addrs: list[tuple[str, int]] = []
     deadline = time.monotonic() + timeout

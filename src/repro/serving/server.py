@@ -42,6 +42,11 @@ single-threaded serving loop:
     the scheduler's SHED path (each waiter receives a terminal ``done``
     event with ``status="shed"`` and ``retry_after``), and keep pumping
     until residents finish token-identically.
+  - **Drive failure**: an exception on the drive thread (a compile
+    error, a device fault) is recorded, never swallowed. Every open
+    stream gets a terminal ``done`` event with ``status="failed"`` and
+    the error, every later stream a ``rejected`` event with
+    ``error="failed"``, and ``shutdown()`` re-raises the exception.
 
 Wire events (one JSON object per SSE ``data:`` frame / NDJSON line):
 
@@ -50,6 +55,7 @@ Wire events (one JSON object per SSE ``data:`` frame / NDJSON line):
   {"event":"done",     "rid":7, "status":"finished", "tokens":[[...]],
                        "lengths":[...], "logprobs":[...], "text":"..."}
   {"event":"done",     "rid":8, "status":"shed", "retry_after":24.0}
+  {"event":"done",     "rid":9, "status":"failed", "error":"..."}
   {"event":"rejected", "error":"quota", "tenant":"t1", "retry_after":1.0}
   {"event":"rejected", "error":"rate",  "tenant":"t1", "retry_after":0.4}
 
@@ -282,6 +288,8 @@ class FrontDoorServer:
         self._loop_thread: threading.Thread | None = None
         self._drive_thread: threading.Thread | None = None
         self._started = threading.Event()
+        # what ended the drive loop, if it raised (re-raised by shutdown)
+        self.error: Exception | None = None
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> "FrontDoorServer":
@@ -359,6 +367,8 @@ class FrontDoorServer:
                 pass   # loop already torn down
         if self._loop_thread is not None:
             self._loop_thread.join(timeout=10.0)
+        if self.error is not None:
+            raise self.error
 
     def _cmd(self, cmd: tuple) -> None:
         self._cmds.put(cmd)
@@ -460,6 +470,32 @@ class FrontDoorServer:
 
     # --------------------------------------------- drive thread (the engine)
     def _drive(self) -> None:
+        try:
+            self._drive_loop()
+        except Exception as e:   # noqa: BLE001 — re-raised by shutdown()
+            self.error = e
+            self._fail_streams()
+
+    def _fail_streams(self) -> None:
+        """After a drive failure: end every open stream with a terminal
+        ``failed`` event, then answer commands until shutdown — later
+        submissions are rejected, a drain request completes at once."""
+        detail = f"{type(self.error).__name__}: {self.error}"
+        for rid, sub in list(self._subs.items()):
+            self._post(sub["conn"], {"event": "done", "rid": rid,
+                                     "status": "failed", "error": detail})
+            self._post(sub["conn"], None)
+        self._subs.clear()
+        self._drained.set()
+        while not self._stop.is_set():
+            kind, arg = self._cmds.get()
+            if kind == "submit":
+                conn = arg[2]
+                self._post(conn, {"event": "rejected", "error": "failed",
+                                  "detail": detail})
+                self._post(conn, None)
+
+    def _drive_loop(self) -> None:
         eng = self.engine
         while not self._stop.is_set():
             block = not eng.scheduler.pending
@@ -506,7 +542,16 @@ class FrontDoorServer:
             if timeout is not None:
                 spec = dataclasses.replace(
                     spec, deadline=eng.scheduler._now + float(timeout))
-            h = eng.submit_spec(spec)
+            try:
+                h = eng.submit_spec(spec)
+            except (KeyError, TypeError, ValueError) as e:
+                # a request the engine refuses (unknown mode, params over
+                # the group's ceilings) is the client's error, not the
+                # drive's
+                self._post(conn, {"event": "rejected", "error": "bad_request",
+                                  "detail": str(e)})
+                self._post(conn, None)
+                return
             rid = int(h)
             conn.rid = rid
             if tenant is not None:

@@ -272,6 +272,29 @@ def test_router_is_wire_invisible_and_prefix_affine(toy, fleet):
     assert st["index"]["size"] > 0
 
 
+def test_concurrent_burst_spreads_over_replicas(toy, fleet):
+    """Requests that arrive together are booked as they are placed, so a
+    burst spreads instead of piling onto the replica that looked idlest
+    before any of them connected."""
+    import threading
+
+    ds, _, _ = toy
+    _, router = fleet
+    out = [None] * 4
+
+    def one(i):
+        out[i] = sse_events("127.0.0.1", router.port,
+                            {"query": ds.pair(4 + i)[0]})
+
+    ts = [threading.Thread(target=one, args=(i,)) for i in range(4)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    assert all(evs[-1]["status"] == "finished" for evs in out)
+    assert {evs[0]["replica"] for evs in out} == {0, 1}
+
+
 def test_cancel_routes_through_to_the_owning_replica(toy, fleet):
     ds, _, _ = toy
     _, router = fleet
@@ -389,3 +412,20 @@ def test_no_healthy_replica_is_a_typed_retryable_rejection(toy):
     finally:
         router.shutdown()
         srv.shutdown(drain=False)
+
+
+def test_spawn_replicas_refuses_to_share_a_chip(monkeypatch):
+    """A TPU chip belongs to one process: no replica process may be
+    started by a process that holds the TPU, nor two onto one host's
+    chips."""
+    from repro.launch import runtime
+    from repro.serving.fleet import spawn_replicas
+
+    monkeypatch.setattr(runtime, "tpu_backend_live", lambda: True)
+    with pytest.raises(RuntimeError, match="holds every chip"):
+        spawn_replicas(1)
+    monkeypatch.setattr(runtime, "tpu_backend_live", lambda: False)
+    monkeypatch.setattr(runtime, "host_tpu_chips", lambda: 4)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="share this host's TPU chips"):
+        spawn_replicas(2)

@@ -171,6 +171,42 @@ def test_bad_request_and_unknown_route(served):
     assert buf.startswith(b"HTTP/1.1 404")
 
 
+def test_request_the_engine_refuses_is_rejected_not_fatal(toy, served):
+    ds, _, _ = toy
+    _, srv = served
+    events = sse_events("127.0.0.1", srv.port,
+                        {"query": ds.pair(1)[0], "mode": "beam"})
+    assert [ev["event"] for ev in events] == ["rejected"]
+    assert events[0]["error"] == "bad_request"
+    # the drive survived: the next request still finishes
+    events = sse_events("127.0.0.1", srv.port, {"query": ds.pair(1)[0]})
+    assert events[-1]["status"] == "finished"
+    assert srv.error is None
+
+
+def test_drive_failure_fails_streams_and_shutdown_reraises(toy):
+    ds, _, _ = toy
+    eng = _engine(toy)
+
+    def boom(*, realtime=False):
+        raise RuntimeError("megastep failed to compile")
+
+    eng.serve_steps = boom
+    srv = FrontDoorServer(eng, ServerConfig(realtime=False)).start()
+    try:
+        events = sse_events("127.0.0.1", srv.port, {"query": ds.pair(1)[0]})
+        assert [ev["event"] for ev in events] == ["accepted", "done"]
+        assert events[-1]["status"] == "failed"
+        assert "megastep failed to compile" in events[-1]["error"]
+        later = sse_events("127.0.0.1", srv.port, {"query": ds.pair(2)[0]})
+        assert [ev["event"] for ev in later] == ["rejected"]
+        assert later[0]["error"] == "failed"
+        assert isinstance(srv.error, RuntimeError)
+    finally:
+        with pytest.raises(RuntimeError, match="megastep failed to compile"):
+            srv.shutdown(drain=True)
+
+
 # ---------------------------------------------------------------------------
 # 2. wire-level cancel
 
