@@ -259,9 +259,13 @@ def grouped_step(specs, handle: DecoderHandle,
     out, lo = [], 0
     for spec, gs in zip(specs, gstate.groups):
         hi = lo + spec.n_rows
-        st = gs._replace(cache=slice_rows(cache, lo, hi))
+        # moving the group's rows out of and back into the shared cache is
+        # cache traffic of the same kind as the beam reorder
+        with jax.named_scope("reorder"):
+            st = gs._replace(cache=slice_rows(cache, lo, hi))
         st = session_step(spec, handle, st)
-        cache = merge_rows(cache, st.cache, lo, hi)
+        with jax.named_scope("reorder"):
+            cache = merge_rows(cache, st.cache, lo, hi)
         out.append(st._replace(cache=None))
         lo = hi
     return GroupedState(groups=tuple(out), cache=cache)
@@ -1264,18 +1268,20 @@ def _forward(spec: SessionSpec, handle: DecoderHandle, state: SessionState):
     position -1 so their cache writes land in the trash slot/page — a
     freed (or mid-prefill, see ``serving.backend``) slot's rows are never
     clobbered by the shared step."""
-    S, K, N_d, DL = (spec.n_slots, spec.n_beams, spec.n_drafts,
-                     spec.draft_len)
-    rel = jnp.arange(DL + 1, dtype=jnp.int32)
-    last_e = jnp.repeat(state.last.reshape(S * K), N_d)
-    drafts_rows = jnp.broadcast_to(
-        state.drafts[:, None], (S, K, N_d, DL)).reshape(S * K * N_d, DL)
-    toks = jnp.concatenate([last_e[:, None], drafts_rows], axis=1)
-    pos_e = jnp.repeat(state.pos.reshape(S * K), N_d)[:, None] + rel[None, :]
-    active_e = jnp.repeat(state.active, K * N_d)
-    pos_e = jnp.where(active_e[:, None], pos_e, -1)
-    logits, cache = handle.decode_step(state.cache, toks, pos_e)
-    return logits, cache, drafts_rows, rel
+    with jax.named_scope("decoder"):
+        S, K, N_d, DL = (spec.n_slots, spec.n_beams, spec.n_drafts,
+                         spec.draft_len)
+        rel = jnp.arange(DL + 1, dtype=jnp.int32)
+        last_e = jnp.repeat(state.last.reshape(S * K), N_d)
+        drafts_rows = jnp.broadcast_to(
+            state.drafts[:, None], (S, K, N_d, DL)).reshape(S * K * N_d, DL)
+        toks = jnp.concatenate([last_e[:, None], drafts_rows], axis=1)
+        pos_e = (jnp.repeat(state.pos.reshape(S * K), N_d)[:, None]
+                 + rel[None, :])
+        active_e = jnp.repeat(state.active, K * N_d)
+        pos_e = jnp.where(active_e[:, None], pos_e, -1)
+        logits, cache = handle.decode_step(state.cache, toks, pos_e)
+        return logits, cache, drafts_rows, rel
 
 
 def _greedy_family_step(spec: SessionSpec, handle: DecoderHandle,
@@ -1286,64 +1292,68 @@ def _greedy_family_step(spec: SessionSpec, handle: DecoderHandle,
     max_new, pad_id = spec.max_new, spec.pad_id
     logits, cache, _, rel = _forward(spec, handle, state)
 
-    finished = state.finished[:, 0] | ~state.active
-    last, pos = state.last[:, 0], state.pos[:, 0]
-    n_out, out = state.n_out[:, 0], state.tokens[:, 0]
-    max_out = state.max_out                                      # (S,)
+    with jax.named_scope("select"):
+        finished = state.finished[:, 0] | ~state.active
+        last, pos = state.last[:, 0], state.pos[:, 0]
+        n_out, out = state.n_out[:, 0], state.tokens[:, 0]
+        max_out = state.max_out                                      # (S,)
 
-    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    greedy_tok = greedy_tok.reshape(S, N_d, DL + 1)
+        greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        greedy_tok = greedy_tok.reshape(S, N_d, DL + 1)
 
-    # --- accept / select best draft --------------------------------------
-    # per-request draft windows: clamping the accept length to the slot's
-    # eff_dl BEFORE best-draft selection makes a padded (N_d, DL) draft
-    # matrix behave exactly like a DL'=eff_dl session (causal logits at
-    # positions <= eff_dl are unaffected by the extra fed draft tokens)
-    n_acc = _accept_lengths(greedy_tok, state.drafts, state.draft_mask)
-    n_acc = jnp.minimum(n_acc, state.eff_dl[:, None])
-    best = jnp.argmax(n_acc, axis=-1).astype(jnp.int32)          # (S,)
-    # inactive slots must not MOVE rows either (their writes already land
-    # in the trash slot/page): a garbage best != 0 would make sync_winner
-    # clobber row 0 of a mid-prefill slot with a sibling's garbage row
-    best = jnp.where(state.active, best, 0)
-    n_acc_b = jnp.take_along_axis(n_acc, best[:, None], axis=1)[:, 0]
-    new_toks = jnp.take_along_axis(
-        greedy_tok, best[:, None, None], axis=1)[:, 0]           # (S, DL+1)
+        # --- accept / select best draft --------------------------------------
+        # per-request draft windows: clamping the accept length to the slot's
+        # eff_dl BEFORE best-draft selection makes a padded (N_d, DL) draft
+        # matrix behave exactly like a DL'=eff_dl session (causal logits at
+        # positions <= eff_dl are unaffected by the extra fed draft tokens)
+        n_acc = _accept_lengths(greedy_tok, state.drafts, state.draft_mask)
+        n_acc = jnp.minimum(n_acc, state.eff_dl[:, None])
+        best = jnp.argmax(n_acc, axis=-1).astype(jnp.int32)          # (S,)
+        # inactive slots must not MOVE rows either (their writes already land
+        # in the trash slot/page): a garbage best != 0 would make sync_winner
+        # clobber row 0 of a mid-prefill slot with a sibling's garbage row
+        best = jnp.where(state.active, best, 0)
+        n_acc_b = jnp.take_along_axis(n_acc, best[:, None], axis=1)[:, 0]
+        new_toks = jnp.take_along_axis(
+            greedy_tok, best[:, None, None], axis=1)[:, 0]          # (S, DL+1)
 
-    # --- EOS/stop + budget truncation -------------------------------------
-    within = rel[None, :] <= n_acc_b[:, None]
-    is_eos = _is_stop_token(spec, new_toks, state.stop_ids) & within
-    any_eos = jnp.any(is_eos, axis=1)
-    first_eos = jnp.argmax(is_eos, axis=1)
-    n_prop = jnp.where(any_eos, first_eos + 1, n_acc_b + 1)
-    budget = max_out - n_out
-    n_app = jnp.minimum(n_prop, budget)
-    n_app = jnp.where(finished, 0, n_app)
-    hit_eos = any_eos & (first_eos + 1 <= budget) & ~finished
+        # --- EOS/stop + budget truncation ------------------------------------
+        within = rel[None, :] <= n_acc_b[:, None]
+        is_eos = _is_stop_token(spec, new_toks, state.stop_ids) & within
+        any_eos = jnp.any(is_eos, axis=1)
+        first_eos = jnp.argmax(is_eos, axis=1)
+        n_prop = jnp.where(any_eos, first_eos + 1, n_acc_b + 1)
+        budget = max_out - n_out
+        n_app = jnp.minimum(n_prop, budget)
+        n_app = jnp.where(finished, 0, n_app)
+        hit_eos = any_eos & (first_eos + 1 <= budget) & ~finished
 
-    # --- write accepted tokens --------------------------------------------
-    write = rel[None, :] < n_app[:, None]
-    idx = n_out[:, None] + rel[None, :]
-    idx = jnp.where(write, idx, max_new)                         # drop invalid
-    b_idx = jnp.arange(S)[:, None]
-    out = out.at[b_idx, idx].set(new_toks, mode="drop")
+        # --- write accepted tokens -------------------------------------------
+        write = rel[None, :] < n_app[:, None]
+        idx = n_out[:, None] + rel[None, :]
+        idx = jnp.where(write, idx, max_new)                     # drop invalid
+        b_idx = jnp.arange(S)[:, None]
+        out = out.at[b_idx, idx].set(new_toks, mode="drop")
 
-    # --- commit: recurrent-state checkpoint + winner cache sync -----------
-    cache = handle.commit_cache(cache, jnp.repeat(n_app, N_d))
-    cache = sync_winner(cache, best, N_d)
+    with jax.named_scope("reorder"):
+        # --- commit: recurrent-state checkpoint + winner cache sync ----------
+        cache = handle.commit_cache(cache, jnp.repeat(n_app, N_d))
+        cache = sync_winner(cache, best, N_d)
 
-    last_idx = jnp.clip(n_app - 1, 0, DL)
-    new_last = jnp.take_along_axis(new_toks, last_idx[:, None], axis=1)[:, 0]
-    last = jnp.where(n_app > 0, new_last, last)
-    pos = pos + n_app
-    n_out = n_out + n_app
-    new_finished = finished | hit_eos | (n_out >= max_out)
-    acc_used = jnp.minimum(n_acc_b, n_app)
-    return state._replace(
-        tokens=out[:, None], last=last[:, None], pos=pos[:, None],
-        n_out=n_out[:, None], finished=new_finished[:, None], cache=cache,
-        n_calls=state.n_calls + state.active.astype(jnp.int32),
-        accepted=state.accepted + acc_used)
+    with jax.named_scope("select"):
+        last_idx = jnp.clip(n_app - 1, 0, DL)
+        new_last = jnp.take_along_axis(new_toks, last_idx[:, None],
+                                       axis=1)[:, 0]
+        last = jnp.where(n_app > 0, new_last, last)
+        pos = pos + n_app
+        n_out = n_out + n_app
+        new_finished = finished | hit_eos | (n_out >= max_out)
+        acc_used = jnp.minimum(n_acc_b, n_app)
+        return state._replace(
+            tokens=out[:, None], last=last[:, None], pos=pos[:, None],
+            n_out=n_out[:, None], finished=new_finished[:, None], cache=cache,
+            n_calls=state.n_calls + state.active.astype(jnp.int32),
+            accepted=state.accepted + acc_used)
 
 
 def _beam_family_step(spec: SessionSpec, handle: DecoderHandle,
@@ -1358,144 +1368,154 @@ def _beam_family_step(spec: SessionSpec, handle: DecoderHandle,
     V = handle.vocab_size
     logits, cache, drafts_rows, rel = _forward(spec, handle, state)
 
-    fin = state.finished | ~state.active[:, None]                # (S, K)
-    max_out = state.max_out                                      # (S,)
+    with jax.named_scope("select"):
+        fin = state.finished | ~state.active[:, None]                # (S, K)
+        max_out = state.max_out                                      # (S,)
 
-    lp_all = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    lp_all = lp_all.at[:, :, pad_id].set(_NEG)   # pad is never a real emission
-    lp_all = lp_all.reshape(S, K, N_d, A, V)
-    greedy_tok = jnp.argmax(lp_all, axis=-1).astype(jnp.int32)
+        lp_all = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        # pad is never a real emission
+        lp_all = lp_all.at[:, :, pad_id].set(_NEG)
+        lp_all = lp_all.reshape(S, K, N_d, A, V)
+        greedy_tok = jnp.argmax(lp_all, axis=-1).astype(jnp.int32)
 
-    # ---- best draft per beam ---------------------------------------------
-    d4 = drafts_rows.reshape(S, K, N_d, DL)
-    dm = jnp.broadcast_to(state.draft_mask[:, None], (S, K, N_d))
-    n_acc = _accept_lengths(greedy_tok, d4, dm)                  # (S, K, N_d)
-    # per-request draft window (see the greedy-family step): clamp BEFORE
-    # best-draft selection so padded drafts act like eff_dl-length ones
-    n_acc = jnp.minimum(n_acc, state.eff_dl[:, None, None])
-    best = jnp.argmax(n_acc, axis=-1).astype(jnp.int32)          # (S, K)
-    # inactive slots must not MOVE rows (mid-prefill row-0 protection,
-    # same as the greedy family): identity winner ...
-    best = jnp.where(state.active[:, None], best, 0)
+        # ---- best draft per beam --------------------------------------------
+        d4 = drafts_rows.reshape(S, K, N_d, DL)
+        dm = jnp.broadcast_to(state.draft_mask[:, None], (S, K, N_d))
+        n_acc = _accept_lengths(greedy_tok, d4, dm)               # (S, K, N_d)
+        # per-request draft window (see the greedy-family step): clamp BEFORE
+        # best-draft selection so padded drafts act like eff_dl-length ones
+        n_acc = jnp.minimum(n_acc, state.eff_dl[:, None, None])
+        best = jnp.argmax(n_acc, axis=-1).astype(jnp.int32)          # (S, K)
+        # inactive slots must not MOVE rows (mid-prefill row-0 protection,
+        # same as the greedy family): identity winner ...
+        best = jnp.where(state.active[:, None], best, 0)
 
-    def take_best(x):
-        idx = best.reshape(S, K, 1, *([1] * (x.ndim - 3)))
-        return jnp.take_along_axis(x, idx, axis=2)[:, :, 0]
+        def take_best(x):
+            idx = best.reshape(S, K, 1, *([1] * (x.ndim - 3)))
+            return jnp.take_along_axis(x, idx, axis=2)[:, :, 0]
 
-    lp_best = take_best(lp_all)                                  # (S, K, A, V)
-    draft_best = take_best(d4)                                   # (S, K, DL)
-    n_acc_b = jnp.take_along_axis(n_acc, best[..., None], axis=2)[..., 0]
+        lp_best = take_best(lp_all)                              # (S, K, A, V)
+        draft_best = take_best(d4)                                 # (S, K, DL)
+        n_acc_b = jnp.take_along_axis(n_acc, best[..., None], axis=2)[..., 0]
 
-    # ---- candidates of unequal lengths -----------------------------------
-    # cum[a] = sum of draft-token logps for prefix length a
-    d_lp = jnp.take_along_axis(
-        lp_best[:, :, :DL, :], draft_best[..., None], axis=3)[..., 0]
-    cum = jnp.concatenate(
-        [jnp.zeros((S, K, 1), jnp.float32), jnp.cumsum(d_lp, axis=-1)],
-        axis=-1)                                                 # (S, K, A)
-    topv, topi = jax.lax.top_k(lp_best, K)                       # (S, K, A, K)
-    cand_lp = state.logp[:, :, None, None] + cum[..., None] + topv
-    valid_a = rel[None, None, :] <= n_acc_b[..., None]           # (S, K, A)
-    # budget: a+1 tokens must fit the slot's remaining per-request budget
-    valid_a &= ((state.n_out[..., None] + rel[None, None, :] + 1)
-                <= max_out[:, None, None])
-    # prefixes may not extend past a draft EOS/stop token
-    draft_eos = jnp.cumsum(
-        _is_stop_token(spec, draft_best, state.stop_ids).astype(jnp.int32),
-        axis=-1)
-    no_eos_in_prefix = jnp.concatenate(
-        [jnp.ones((S, K, 1), jnp.int32), (draft_eos == 0).astype(jnp.int32)],
-        axis=-1)
-    valid_a &= no_eos_in_prefix.astype(bool)
-    cand_lp = jnp.where(valid_a[..., None], cand_lp, _NEG)
-    # per-request beam width: an eff_beams < K request only ever extends
-    # with the top-eff_beams tokens per (parent, prefix) — the candidate
-    # multiset of a true eff_beams-wide search (ranks >= eff_beams at _NEG)
-    k_rank = jnp.arange(K, dtype=jnp.int32)
-    cand_lp = jnp.where(
-        k_rank[None, None, None, :] < state.eff_beams[:, None, None, None],
-        cand_lp, _NEG)
+        # ---- candidates of unequal lengths ----------------------------------
+        # cum[a] = sum of draft-token logps for prefix length a
+        d_lp = jnp.take_along_axis(
+            lp_best[:, :, :DL, :], draft_best[..., None], axis=3)[..., 0]
+        cum = jnp.concatenate(
+            [jnp.zeros((S, K, 1), jnp.float32), jnp.cumsum(d_lp, axis=-1)],
+            axis=-1)                                                # (S, K, A)
+        topv, topi = jax.lax.top_k(lp_best, K)                   # (S, K, A, K)
+        cand_lp = state.logp[:, :, None, None] + cum[..., None] + topv
+        valid_a = rel[None, None, :] <= n_acc_b[..., None]          # (S, K, A)
+        # budget: a+1 tokens must fit the slot's remaining per-request budget
+        valid_a &= ((state.n_out[..., None] + rel[None, None, :] + 1)
+                    <= max_out[:, None, None])
+        # prefixes may not extend past a draft EOS/stop token
+        draft_eos = jnp.cumsum(
+            _is_stop_token(spec, draft_best, state.stop_ids).astype(jnp.int32),
+            axis=-1)
+        no_eos_in_prefix = jnp.concatenate(
+            [jnp.ones((S, K, 1), jnp.int32),
+             (draft_eos == 0).astype(jnp.int32)],
+            axis=-1)
+        valid_a &= no_eos_in_prefix.astype(bool)
+        cand_lp = jnp.where(valid_a[..., None], cand_lp, _NEG)
+        # per-request beam width: an eff_beams < K request only ever extends
+        # with the top-eff_beams tokens per (parent, prefix) — the candidate
+        # multiset of a true eff_beams-wide search (ranks >= eff_beams at _NEG)
+        k_rank = jnp.arange(K, dtype=jnp.int32)
+        cand_lp = jnp.where(
+            k_rank[None, None, None, :] < state.eff_beams[:, None, None, None],
+            cand_lp, _NEG)
 
-    # Same-path dedup: (a, w=draft[a]) with a < n_acc is a strict prefix of a
-    # longer candidate in this set; keeping it would crowd out genuine
-    # alternatives (only frontier candidates, as in the paper's Fig. 3).
-    d_pad = jnp.pad(draft_best, ((0, 0), (0, 0), (0, 1)), constant_values=-1)
-    dup = ((topi == d_pad[..., None])
-           & (rel[None, None, :, None] < n_acc_b[..., None, None]))
-    cand_lp = jnp.where(dup, _NEG, cand_lp)
+        # Same-path dedup: (a, w=draft[a]) with a < n_acc is a strict prefix
+        # of a longer candidate in this set; keeping it would crowd out
+        # genuine alternatives (only frontier candidates, as in the paper's
+        # Fig. 3).
+        d_pad = jnp.pad(draft_best, ((0, 0), (0, 0), (0, 1)),
+                        constant_values=-1)
+        dup = ((topi == d_pad[..., None])
+               & (rel[None, None, :, None] < n_acc_b[..., None, None]))
+        cand_lp = jnp.where(dup, _NEG, cand_lp)
 
-    # finished beams: single pass-through candidate (a=0, k=0), logp kept
-    pass_lp = jnp.full((A, K), _NEG).at[0, 0].set(0.0)
-    cand_lp = jnp.where(fin[..., None, None],
-                        state.logp[:, :, None, None] + pass_lp[None, None],
-                        cand_lp)
+        # finished beams: single pass-through candidate (a=0, k=0), logp kept
+        pass_lp = jnp.full((A, K), _NEG).at[0, 0].set(0.0)
+        cand_lp = jnp.where(fin[..., None, None],
+                            state.logp[:, :, None, None] + pass_lp[None, None],
+                            cand_lp)
 
-    # ---- per-slot global top-K -------------------------------------------
-    flat = cand_lp.reshape(S, K * A * K)
-    new_logp, flat_idx = jax.lax.top_k(flat, K)                  # (S, K)
-    parent = (flat_idx // (A * K)).astype(jnp.int32)
-    # ... and identity parents, so the beam gather below can never pull a
-    # garbage sibling row over a mid-prefill slot's row 0
-    parent = jnp.where(state.active[:, None], parent, k_rank[None, :])
-    a_len = ((flat_idx // K) % A).astype(jnp.int32)
-    w_tok = jnp.take_along_axis(topi.reshape(S, K * A * K), flat_idx, axis=1)
-    was_fin = jnp.take_along_axis(fin, parent, axis=1)
+        # ---- per-slot global top-K ------------------------------------------
+        flat = cand_lp.reshape(S, K * A * K)
+        new_logp, flat_idx = jax.lax.top_k(flat, K)                  # (S, K)
+        parent = (flat_idx // (A * K)).astype(jnp.int32)
+        # ... and identity parents, so the beam gather below can never pull a
+        # garbage sibling row over a mid-prefill slot's row 0
+        parent = jnp.where(state.active[:, None], parent, k_rank[None, :])
+        a_len = ((flat_idx // K) % A).astype(jnp.int32)
+        w_tok = jnp.take_along_axis(topi.reshape(S, K * A * K), flat_idx,
+                                    axis=1)
+        was_fin = jnp.take_along_axis(fin, parent, axis=1)
 
-    def take_parent(x):
-        idx = parent.reshape(S, K, *([1] * (x.ndim - 2)))
-        return jnp.take_along_axis(x, idx, axis=1)
+    with jax.named_scope("reorder"):
+        def take_parent(x):
+            idx = parent.reshape(S, K, *([1] * (x.ndim - 2)))
+            return jnp.take_along_axis(x, idx, axis=1)
 
-    # ---- materialize new beams (fixed-shape writes) ----------------------
-    out_p = take_parent(state.tokens)                            # (S,K,max_new)
-    nout_p = jnp.take_along_axis(state.n_out, parent, axis=1)
-    drafts_p = take_parent(draft_best)                           # (S, K, DL)
-    # committed tokens this round: draft[:a] ++ w  -> length a+1
-    seg = jnp.where(rel[None, None, :] < a_len[..., None],
-                    jnp.pad(drafts_p, ((0, 0), (0, 0), (0, 1))),
-                    jnp.where(rel[None, None, :] == a_len[..., None],
-                              w_tok[..., None], pad_id))
-    n_new = jnp.where(was_fin, 0, a_len + 1)
-    idx = nout_p[..., None] + rel[None, None, :]
-    idx = jnp.where(rel[None, None, :] < n_new[..., None], idx, max_new)
-    s_ix = jnp.arange(S)[:, None, None]
-    k_ix = jnp.arange(K)[None, :, None]
-    out_new = out_p.at[s_ix, k_ix, idx].set(seg, mode="drop")
+        # ---- materialize new beams (fixed-shape writes) ---------------------
+        out_p = take_parent(state.tokens)                       # (S,K,max_new)
+        nout_p = jnp.take_along_axis(state.n_out, parent, axis=1)
+        drafts_p = take_parent(draft_best)                         # (S, K, DL)
+        # committed tokens this round: draft[:a] ++ w  -> length a+1
+        seg = jnp.where(rel[None, None, :] < a_len[..., None],
+                        jnp.pad(drafts_p, ((0, 0), (0, 0), (0, 1))),
+                        jnp.where(rel[None, None, :] == a_len[..., None],
+                                  w_tok[..., None], pad_id))
+        n_new = jnp.where(was_fin, 0, a_len + 1)
+        idx = nout_p[..., None] + rel[None, None, :]
+        idx = jnp.where(rel[None, None, :] < n_new[..., None], idx, max_new)
+        s_ix = jnp.arange(S)[:, None, None]
+        k_ix = jnp.arange(K)[None, :, None]
+        out_new = out_p.at[s_ix, k_ix, idx].set(seg, mode="drop")
 
-    new_finished = (was_fin | _is_stop_token(spec, w_tok, state.stop_ids)
-                    | (nout_p + n_new >= max_out[:, None]))
-    # beams past the slot's eff_beams are parked: _NEG log-prob + finished,
-    # so they never spawn candidates and sort last at read-out — the slot
-    # behaves as a true eff_beams-wide search (no-op when eff_beams == K)
-    parked = k_rank[None, :] >= state.eff_beams[:, None]
-    new_logp = jnp.where(parked, _NEG, new_logp)
-    new_finished = new_finished | parked
-    new_last = jnp.where(was_fin,
-                         jnp.take_along_axis(state.last, parent, axis=1),
-                         w_tok)
-    new_pos = jnp.take_along_axis(state.pos, parent, axis=1) + n_new
-    new_nout = nout_p + n_new
+        new_finished = (was_fin | _is_stop_token(spec, w_tok, state.stop_ids)
+                        | (nout_p + n_new >= max_out[:, None]))
+        # beams past the slot's eff_beams are parked: _NEG log-prob + finished,
+        # so they never spawn candidates and sort last at read-out — the slot
+        # behaves as a true eff_beams-wide search (no-op when eff_beams == K)
+        parked = k_rank[None, :] >= state.eff_beams[:, None]
+        new_logp = jnp.where(parked, _NEG, new_logp)
+        new_finished = new_finished | parked
+        new_last = jnp.where(was_fin,
+                             jnp.take_along_axis(state.last, parent, axis=1),
+                             w_tok)
+        new_pos = jnp.take_along_axis(state.pos, parent, axis=1) + n_new
+        new_nout = nout_p + n_new
 
-    # ---- cache: winner-draft row of the parent beam, then commit the
-    # candidate's own prefix length (recurrent-state rollback) -------------
-    best_p = jnp.take_along_axis(best, parent, axis=1)           # (S, K)
-    base = (jnp.arange(S, dtype=jnp.int32) * K)[:, None]
-    src = ((base + parent) * N_d + best_p).reshape(-1)
-    cache = gather_rows(cache, jnp.repeat(src, N_d))
-    n_keep = jnp.where(was_fin, 0, a_len + 1)
-    cache = handle.commit_cache(cache, jnp.repeat(n_keep.reshape(-1), N_d))
+        # ---- cache: winner-draft row of the parent beam, then commit the
+        # candidate's own prefix length (recurrent-state rollback) ------------
+        best_p = jnp.take_along_axis(best, parent, axis=1)           # (S, K)
+        base = (jnp.arange(S, dtype=jnp.int32) * K)[:, None]
+        src = ((base + parent) * N_d + best_p).reshape(-1)
+        cache = gather_rows(cache, jnp.repeat(src, N_d))
+        n_keep = jnp.where(was_fin, 0, a_len + 1)
+        cache = handle.commit_cache(cache, jnp.repeat(n_keep.reshape(-1), N_d))
 
-    acc = jnp.where(state.active & ~was_fin[:, 0], a_len[:, 0], 0)
-    return state._replace(
-        tokens=out_new, logp=new_logp, last=new_last, pos=new_pos,
-        n_out=new_nout, finished=new_finished, cache=cache,
-        n_calls=state.n_calls + state.active.astype(jnp.int32),
-        accepted=state.accepted + acc)
+        acc = jnp.where(state.active & ~was_fin[:, 0], a_len[:, 0], 0)
+        return state._replace(
+            tokens=out_new, logp=new_logp, last=new_last, pos=new_pos,
+            n_out=new_nout, finished=new_finished, cache=cache,
+            n_calls=state.n_calls + state.active.astype(jnp.int32),
+            accepted=state.accepted + acc)
 
 
 def session_step(spec: SessionSpec, handle: DecoderHandle,
                  state: SessionState) -> SessionState:
     """ONE decode iteration for every slot: verify forward pass -> accept ->
-    commit. Pure and shape-stable — jit it once per SessionSpec."""
+    commit. Pure and shape-stable — jit it once per SessionSpec. The three
+    parts run under the named scopes ``decoder``, ``select`` and
+    ``reorder``, which the compiled program keeps as op metadata
+    (``StreamingEngine.op_scopes()``)."""
     if spec.kind == "greedy":
         if spec.n_beams != 1:
             raise ValueError("greedy-family sessions require n_beams == 1")

@@ -478,7 +478,8 @@ def cached_attention(p: dict, cfg: ModelConfig, x, cache, positions,
                 cache.block_tables, positions,
                 window=cfg.sliding_window)
             return dense(p["wo"], out.reshape(B, T, -1)), cache
-        k, v, kpos = paged_view(cache)
+        with jax.named_scope("page_view"):
+            k, v, kpos = paged_view(cache)
     else:
         cache = _write_cache(cache, k_new, v_new, positions)
         k, v, kpos = cache.k, cache.v, cache.pos

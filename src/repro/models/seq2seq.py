@@ -191,7 +191,9 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions, *,
     ``positions``: (B, T) absolute target positions (rows may differ) — this
     is the JAX-native equivalent of the paper's padLeft + shifted positional
     encodings (DESIGN.md §2). When no explicit ``memory_mask`` is passed the
-    per-row mask stored in the cache (if any) applies.
+    per-row mask stored in the cache (if any) applies. Each layer's parts
+    run under the named scopes ``self_attn``, ``cross_attn`` and ``ffn``,
+    the output projection under ``lm_head``.
     """
     if memory_mask is None and "mmask" in cache:
         memory_mask = cache["mmask"][0]
@@ -199,21 +201,25 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions, *,
 
     def body(h, xs):
         p, c_self, c_cross = xs
-        a, c_self = cached_attention(
-            p["self_attn"], cfg, apply_norm(p["norm1"], h, cfg.norm), c_self,
-            positions)
-        h = h + a
-        c = attn_mod.cached_cross_attention(
-            p["cross_attn"], cfg, apply_norm(p["norm_x"], h, cfg.norm), c_cross,
-            memory_mask=memory_mask)
-        h = h + c
-        f = ffn(p["ffn"], apply_norm(p["norm2"], h, cfg.norm))
-        return h + f, c_self
+        with jax.named_scope("self_attn"):
+            a, c_self = cached_attention(
+                p["self_attn"], cfg, apply_norm(p["norm1"], h, cfg.norm),
+                c_self, positions)
+            h = h + a
+        with jax.named_scope("cross_attn"):
+            c = attn_mod.cached_cross_attention(
+                p["cross_attn"], cfg, apply_norm(p["norm_x"], h, cfg.norm),
+                c_cross, memory_mask=memory_mask)
+            h = h + c
+        with jax.named_scope("ffn"):
+            f = ffn(p["ffn"], apply_norm(p["norm2"], h, cfg.norm))
+            return h + f, c_self
 
     x, new_self = jax.lax.scan(
         body, x, (params["dec_blocks"], cache["self"], cache["cross"]))
-    x = apply_norm(params["dec_norm"], x, cfg.norm)
-    logits = x @ params["lm_head"]["w_vocab"]
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["dec_norm"], x, cfg.norm)
+        logits = x @ params["lm_head"]["w_vocab"]
     new_cache = {"self": new_self, "cross": cache["cross"]}
     if "mmask" in cache:
         new_cache["mmask"] = cache["mmask"]

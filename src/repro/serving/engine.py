@@ -101,6 +101,7 @@ from repro.serving.api import (MAX_STOP_IDS, GenerationParams,
 from repro.serving.backend import make_backend
 from repro.serving.scheduler import (ContinuousScheduler, OverloadPolicy,
                                      SlotResult)
+from repro.serving import tracing
 
 
 @dataclasses.dataclass
@@ -532,11 +533,13 @@ class StreamingEngine:
             self._admit_cached_fns = {m: self._make_admit_cached(m)
                                       for m in self._groups}
         # dispatch-ahead loop instrumentation: total jitted dispatches,
-        # per-iteration dispatch counts, and host step-gap samples (time
-        # between consecutive bundle syncs) — bounded, benchmark-read
+        # synced iterations and those that took exactly one dispatch (the
+        # dispatches between the first and the latest sync are
+        # ``_disp_mark - _disp_base``), and host step-gap samples (time
+        # between consecutive bundle syncs, bounded) — benchmark-read
         self.n_dispatches = 0
-        self._disp_mark = 0
-        self._dispatch_samples: list[int] = []
+        self._disp_mark = self._disp_base = 0
+        self._n_iterations = self._n_one_dispatch = 0
         self._step_gaps: list[float] = []
         self._last_sync_t: float | None = None
         # host-side chunked-prefill bookkeeping: global slot ->
@@ -605,10 +608,17 @@ class StreamingEngine:
         grouped decode step. Returns ``(gstate, bundle)`` where the bundle
         holds everything the host syncs on: the finished mask, committed
         counts + greedy stream deltas, and the page counters that feed the
-        mirrored admission accounting."""
+        mirrored admission accounting.
+
+        Named scopes split the program for a device trace
+        (``op_scopes()``): ``page_plan`` (plan, apply, chunk writes), the
+        session step's ``decoder``/``select``/``reorder``, and ``bundle``.
+        The jitted function keeps its name: the compiled module is
+        ``jit__megastep_impl``, which the chip benchmark matches."""
         specs = tuple(self._groups.values())
         handle = self.backend.step_handle(params)
-        n_out0 = self._slot_counts(gstate)
+        with jax.named_scope("bundle"):
+            n_out0 = self._slot_counts(gstate)
         plan = None
         if self.ecfg.paged:
             n_pages, ps = self._paged_geometry()
@@ -623,20 +633,24 @@ class StreamingEngine:
                     in zip(self.mode_names, prefill))
             shards = ((self.n_shards, self._row_shard)
                       if self.n_shards > 1 else None)
-            plan = device_page_plan(specs, blocks, ps, n_pages, gstate,
-                                    prefill=plan_prefill, shards=shards)
+            with jax.named_scope("page_plan"):
+                plan = device_page_plan(specs, blocks, ps, n_pages, gstate,
+                                        prefill=plan_prefill, shards=shards)
 
             def body(g):
-                g = GroupedState(groups=g.groups,
-                                 cache=apply_page_plan(g.cache, plan))
-                g = self._write_chunks(params, g, prefill)
+                with jax.named_scope("page_plan"):
+                    g = GroupedState(groups=g.groups,
+                                     cache=apply_page_plan(g.cache, plan))
+                    g = self._write_chunks(params, g, prefill)
                 return grouped_step(specs, handle, g)
 
             gstate = jax.lax.cond(plan.exhausted, lambda g: g, body, gstate)
         else:
-            gstate = self._write_chunks(params, gstate, prefill)
+            with jax.named_scope("page_plan"):
+                gstate = self._write_chunks(params, gstate, prefill)
             gstate = grouped_step(specs, handle, gstate)
-        return gstate, self._make_bundle(gstate, n_out0, plan)
+        with jax.named_scope("bundle"):
+            return gstate, self._make_bundle(gstate, n_out0, plan)
 
     def _slot_counts(self, gstate) -> jnp.ndarray:
         """(n_slots,) committed-token counts on each slot's row 0, global
@@ -929,7 +943,7 @@ class StreamingEngine:
         self._dispatch_rids = {s: r.rid
                                for s, r in self.scheduler._resident.items()}
         self._dispatch_prefilling = set(self._prefilling)
-        with jax.profiler.TraceAnnotation("serve/megastep"):
+        with jax.profiler.TraceAnnotation(tracing.MEGASTEP):
             if prefill is None:
                 state, bundle = self._megastep_fn(self.params, state)
             else:
@@ -947,9 +961,21 @@ class StreamingEngine:
         whose prompt is fully written, refresh the mirrored page counters,
         stash the stream deltas, and build the eviction mask (guarded by
         the dispatch-time rid snapshot, so a slot recycled since dispatch
-        is never evicted by a stale mask)."""
-        with jax.profiler.TraceAnnotation("serve/readout"):
+        is never evicted by a stale mask).
+
+        Spans: ``serve/wait`` while the chip still runs the megastep,
+        ``serve/readout`` for the host copies of the ready bundle,
+        ``serve/sync`` for the rest."""
+        with jax.profiler.TraceAnnotation(tracing.WAIT):
+            jax.block_until_ready(self._bundle)
+        with jax.profiler.TraceAnnotation(tracing.READOUT):
             out = {k: np.asarray(v) for k, v in self._bundle.items()}
+        with jax.profiler.TraceAnnotation(tracing.SYNC):
+            return self._apply_bundle(out)
+
+    def _apply_bundle(self, out: dict) -> dict:
+        """The host-side consequences of one synced bundle (see
+        ``_sync_step``)."""
         t = time.perf_counter()
         if self._last_sync_t is not None:
             self._step_gaps.append(t - self._last_sync_t)
@@ -973,9 +999,9 @@ class StreamingEngine:
                 ex = np.asarray(out["exhausted_sh"], bool)
                 shard = int(np.argmax(ex)) if ex.any() else None
             return {"exhausted": True, "group": prefer, "shard": shard}
-        self._dispatch_samples.append(self.n_dispatches - self._disp_mark)
-        if len(self._dispatch_samples) > 4096:
-            del self._dispatch_samples[:2048]
+        self._n_iterations += 1
+        if self.n_dispatches - self._disp_mark == 1:
+            self._n_one_dispatch += 1
         self._disp_mark = self.n_dispatches
         for slot in self._staged_slots:     # dispatched chunks are written
             rec = self._prefilling.get(slot)
@@ -1193,7 +1219,7 @@ class StreamingEngine:
             if shard is not None:
                 self._admits_by_shard[shard] += 1
             self.requests_admitted += 1
-            with jax.profiler.TraceAnnotation("serve/admit"):
+            with jax.profiler.TraceAnnotation(tracing.ADMIT):
                 if not self.backend.chunked:
                     self._decoding.add(slot)
                     self.n_dispatches += 1
@@ -1538,8 +1564,11 @@ class StreamingEngine:
     def loop_stats(self) -> dict:
         """Host-loop instrumentation for the serving benchmark: total
         jitted dispatches, dispatches per scheduler iteration (steady
-        state == 1.0: the fused megastep), and the host step-gap (seconds
-        between consecutive bundle syncs) p50/p95."""
+        state == 1.0: the fused megastep), the host step-gap (seconds
+        between consecutive bundle syncs) p50/p95, and slot occupancy:
+        ``slot_steps`` (resident slots summed over every dispatched
+        megastep, replays included) over ``capacity_steps`` (``n_slots``
+        per megastep)."""
         gaps = sorted(self._step_gaps)
 
         def pct(q):
@@ -1547,17 +1576,29 @@ class StreamingEngine:
                 return 0.0
             return gaps[min(len(gaps) - 1, int(q * len(gaps)))]
 
-        samples = self._dispatch_samples
+        n = self._n_iterations
+        sch = self.scheduler
         return {
             "n_dispatches": self.n_dispatches,
-            "n_iterations": len(samples),
-            "dispatches_per_iteration": (sum(samples) / len(samples)
-                                         if samples else 0.0),
-            "steady_iterations_one_dispatch": sum(1 for s in samples
-                                                  if s == 1),
+            "n_iterations": n,
+            "dispatches_per_iteration": ((self._disp_mark - self._disp_base)
+                                         / n if n else 0.0),
+            "steady_iterations_one_dispatch": self._n_one_dispatch,
             "step_gap_p50_s": pct(0.50),
             "step_gap_p95_s": pct(0.95),
+            "slot_steps": sch.slot_steps,
+            "capacity_steps": sch.capacity_steps,
+            "slot_occupancy": sch.slot_occupancy,
         }
+
+    def op_scopes(self) -> dict[str, str]:
+        """HLO instruction name -> named-scope path (``decoder/self_attn``)
+        of the decode-only megastep program, ``jit__megastep_impl``: the
+        key a profiler trace's operation names join on
+        (``repro.serving.tracing.op_scopes``). Compiles the program again
+        for its text (a hit where a persistent compile cache is on)."""
+        return tracing.op_scopes(self._megastep_fn.lower(
+            self.params, self.scheduler.state).compile().as_text())
 
     def cache_footprint(self) -> dict:
         """Self-attention cache HBM accounting for the serving benchmark.
@@ -1642,8 +1683,9 @@ class StreamingEngine:
         self._done, self._epoch, self._streams = {}, {}, {}
         self._pump = None
         self._pump_realtime = False
-        self._dispatch_samples, self._step_gaps = [], []
-        self._disp_mark = self.n_dispatches
+        self._n_iterations = self._n_one_dispatch = 0
+        self._step_gaps = []
+        self._disp_mark = self._disp_base = self.n_dispatches
 
     def submit_spec(self, rspec: RequestSpec) -> RequestHandle:
         """THE canonical entry point: enqueue one fully-specified
@@ -1721,9 +1763,10 @@ class StreamingEngine:
     def _serve_steps_impl(self, realtime: bool):
         for events in self.scheduler.steps(self._read_slot,
                                            realtime=realtime):
-            self._collect_streams()
-            for r in events:
-                self._finish_result(r)
+            with jax.profiler.TraceAnnotation(tracing.RESULTS):
+                self._collect_streams()
+                for r in events:
+                    self._finish_result(r)
             yield events
 
     def _ensure_pump(self, realtime: bool = False):

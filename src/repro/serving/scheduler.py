@@ -101,10 +101,12 @@ import math
 import time
 from typing import Any, Callable, Hashable
 
+import jax
 import numpy as np
 
 from repro.core.session import PoolExhausted, SessionSpec, release_slot
 from repro.serving.api import RequestStatus
+from repro.serving.tracing import EVICT, SCHEDULE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -302,6 +304,10 @@ class ContinuousScheduler:
         self.n_expired = 0
         self.n_shed = 0
         self.max_resident = 0
+        # slot occupancy: resident slots summed over every dispatched step
+        # (replays included) against the slots those steps could have held
+        self.slot_steps = 0
+        self.capacity_steps = 0
         self._skipped = 0.0   # closed-loop clock offset from idle jumps
         self._now = 0.0       # last serving-clock reading (for cancel())
         self.draining = False   # True: every submission sheds (shutdown)
@@ -392,6 +398,11 @@ class ContinuousScheduler:
     @property
     def pending(self) -> int:
         return self.queued + len(self._resident)
+
+    @property
+    def slot_occupancy(self) -> float:
+        """Share of dispatched slot-steps that held a resident request."""
+        return self.slot_steps / max(1, self.capacity_steps)
 
     def cancel(self, rid: int) -> SlotResult | None:
         """Abandon a request: a queued one is dequeued immediately, a
@@ -716,21 +727,47 @@ class ContinuousScheduler:
                         mask=None) -> list[SlotResult]:
         if not self._resident:
             return []
-        finished = self._finished(self.state) if mask is None else mask
-        done, results = [s for s in self._resident if finished[s]], []
-        for slot in done:
-            # read while the slot is still resident: the engine's read_slot
-            # looks up the request's per-request params to trim the view
-            fields = read_slot(self.state, slot)
-            req, admitted = self._evict(slot)
-            service = max(0.0, now - admitted)
-            prev = self._ewma_service.get(req.mode)
-            self._ewma_service[req.mode] = (
-                service if prev is None else 0.8 * prev + 0.2 * service)
-            results.append(SlotResult(
-                rid=req.rid, arrival=req.arrival, mode=req.mode,
-                admitted=admitted, completed=now, **fields))
+        with jax.profiler.TraceAnnotation(EVICT):
+            finished = self._finished(self.state) if mask is None else mask
+            done, results = [s for s in self._resident if finished[s]], []
+            for slot in done:
+                # read while the slot is still resident: the engine's
+                # read_slot looks up the request's per-request params to
+                # trim the view
+                fields = read_slot(self.state, slot)
+                req, admitted = self._evict(slot)
+                service = max(0.0, now - admitted)
+                prev = self._ewma_service.get(req.mode)
+                self._ewma_service[req.mode] = (
+                    service if prev is None else 0.8 * prev + 0.2 * service)
+                results.append(SlotResult(
+                    rid=req.rid, arrival=req.arrival, mode=req.mode,
+                    admitted=admitted, completed=now, **fields))
         return results
+
+    def _count_step(self) -> None:
+        """Occupancy counters for one dispatched step."""
+        self.slot_steps += len(self._resident)
+        self.capacity_steps += len(self._slot_key)
+
+    def _schedule(self, clock, realtime: bool,
+                  inflight: bool = False) -> tuple:
+        """Top of an iteration: expire residents past their deadline, jump
+        an idle closed-loop clock to the next arrival, admit. Returns
+        (now, next arrival, terminal events)."""
+        with jax.profiler.TraceAnnotation(SCHEDULE):
+            self._now = now = clock()
+            events: list[SlotResult] = []
+            self._expire_residents(now, events)
+            nxt = self._next_arrival()
+            if (not self._resident and not inflight and nxt is not None
+                    and not realtime and nxt > now):
+                # idle: fast-forward the clock to the next arrival (persisted
+                # in the offset so admitted/completed stamps stay monotone)
+                self._skipped += nxt - now
+                self._now = now = clock()
+            self._admit_ready(now, events)
+        return now, nxt, events
 
     def _rewind_clock(self) -> None:
         """Each drive restarts the serving clock at 0, but submissions made
@@ -784,17 +821,7 @@ class ContinuousScheduler:
                        + (self._skipped - skip0)))
         self._rewind_clock()
         while self.queued or self._resident:
-            self._now = now = clock()
-            events: list[SlotResult] = []
-            self._expire_residents(now, events)
-            nxt = self._next_arrival()
-            if (not self._resident and nxt is not None and not realtime
-                    and nxt > now):
-                # idle: fast-forward the clock to the next arrival (persisted
-                # in the offset so admitted/completed stamps stay monotone)
-                self._skipped += nxt - now
-                self._now = now = clock()
-            self._admit_ready(now, events)
+            now, nxt, events = self._schedule(clock, realtime)
             if not self._resident:
                 if realtime and nxt is not None:
                     # nothing can change until the head arrives: sleep it off
@@ -803,6 +830,7 @@ class ContinuousScheduler:
                     yield events
                 continue
             self._prepare()
+            self._count_step()
             self.state = self._step(self.state)
             self.n_steps += 1
             self._now = done_t = clock()
@@ -840,15 +868,7 @@ class ContinuousScheduler:
         self._rewind_clock()
         inflight = False
         while self.queued or self._resident or inflight:
-            self._now = now = clock()
-            events: list[SlotResult] = []
-            self._expire_residents(now, events)
-            nxt = self._next_arrival()
-            if (not self._resident and not inflight and nxt is not None
-                    and not realtime and nxt > now):
-                self._skipped += nxt - now
-                self._now = now = clock()
-            self._admit_ready(now, events)
+            now, nxt, events = self._schedule(clock, realtime, inflight)
             if inflight:
                 out = self._sync()
                 while out.get("exhausted"):
@@ -869,6 +889,7 @@ class ContinuousScheduler:
                         self._preempt_youngest(
                             prefer if prefer in self._future else None,
                             shard=shard)
+                    self._count_step()
                     self.state = self._dispatch(self.state)
                     out = self._sync()
                 inflight = False
@@ -877,6 +898,7 @@ class ContinuousScheduler:
                 events.extend(self._evict_finished(done_t, read_slot,
                                                    mask=out["finished"]))
             if self._resident:
+                self._count_step()
                 self.state = self._dispatch(self.state)
                 inflight = True
             elif realtime and nxt is not None:

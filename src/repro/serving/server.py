@@ -643,7 +643,8 @@ class FrontDoorServer:
         """Server + engine observability, served on ``GET /v1/stats`` /
         ``{"op":"stats"}``. Beyond the door's own counters this surfaces
         the engine's load shape — ``occupancy`` ((resident + queued) /
-        n_slots), ``shed_rate`` (shed / offered) — plus the full
+        n_slots), ``slot_occupancy`` (share of dispatched slot-steps that
+        held a request), ``shed_rate`` (shed / offered) — plus the full
         ``shard_stats()`` / ``prefix_stats()`` / overload counters, which
         is exactly what the fleet router's placement policy consumes
         (``repro.serving.fleet``); it is equally useful standalone (one
@@ -667,6 +668,9 @@ class FrontDoorServer:
             "occupancy": (resident + sch.queued) / max(1, eng.n_slots),
             "shed_rate": sch.n_shed / max(1, offered),
             "n_steps": sch.n_steps,
+            "slot_steps": sch.slot_steps,
+            "capacity_steps": sch.capacity_steps,
+            "slot_occupancy": sch.slot_occupancy,
             "n_shed": sch.n_shed,
             "n_cancelled": sch.n_cancelled,
             "n_expired": sch.n_expired,

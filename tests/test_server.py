@@ -384,6 +384,9 @@ def test_stats_expose_engine_load_shape_and_shard_prefix_counters(toy):
         assert stats["accepted"] == 1 and stats["accepting"] is True
         assert stats["n_slots"] == 1 and stats["resident"] == 0
         assert stats["occupancy"] == 0.0 and stats["shed_rate"] == 0.0
+        # one slot, resident through every step that served the request
+        assert stats["slot_steps"] == stats["capacity_steps"] > 0
+        assert stats["slot_occupancy"] == 1.0
         assert stats["rate_limited"] == 0
         assert isinstance(stats["shard_stats"], (list, dict))
         assert isinstance(stats["prefix_stats"], dict)
