@@ -1,5 +1,6 @@
 """jit-able wrappers matching the model cache layouts: dense (B, S, Kv, hd)
-rows and the ``PagedKVCache`` pool/block-table pair."""
+rows and the ``PagedKVCache`` pool/block-table pair, whose pages hold
+(ps, Kv * hd) rows with the heads folded."""
 
 from __future__ import annotations
 
@@ -43,15 +44,17 @@ def paged_decode_gqa_attention(q, k_pool, v_pool, pos_pool, block_tables,
     (B, n_blocks*ps, ...) view; at serving batch sizes that copy dwarfs the
     attention math).
 
-    q: (B, T, H, hd); k/v_pool: (P, ps, Kv, hd) (the ``PagedKVCache`` pool
-    layout for one layer); pos_pool: (P, ps) stored positions (-1 empty);
-    block_tables: (B, n_blocks) page ids (-1 unmapped); q_pos: (B, T).
-    Returns (B, T, H, hd)."""
+    q: (B, T, H, hd); k/v_pool: (P, ps, Kv * hd) (the ``PagedKVCache`` pool
+    layout for one layer, heads folded); pos_pool: (P, ps) stored positions
+    (-1 empty); block_tables: (B, n_blocks) page ids (-1 unmapped); q_pos:
+    (B, T). Returns (B, T, H, hd)."""
     B, T, H, hd = q.shape
-    Kv = k_pool.shape[2]
+    P, ps, F = k_pool.shape
+    Kv = F // hd
     q_r = _split_heads(q, Kv)
-    k_r = k_pool.transpose(0, 2, 1, 3)      # (P, Kv, ps, hd)
-    v_r = v_pool.transpose(0, 2, 1, 3)
+    # the kernel tiles one page per head: heads split at its boundary
+    k_r = k_pool.reshape(P, ps, Kv, hd).transpose(0, 2, 1, 3)  # (P, Kv, ps, hd)
+    v_r = v_pool.reshape(P, ps, Kv, hd).transpose(0, 2, 1, 3)
     out = paged_decode_gqa_kernel(
         block_tables.astype(jnp.int32), q_r, k_r, v_r, pos_pool[:, None],
         _row_positions(q_pos, H // Kv), window=window,

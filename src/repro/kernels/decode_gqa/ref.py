@@ -18,14 +18,14 @@ def paged_decode_gqa_ref(q, k_pool, v_pool, pos_pool, block_tables, q_pos,
     """Paged oracle: gather each row's mapped pages into the dense view,
     then run the dense oracle (mirrors ``models.attention.paged_view``).
 
-    q: (B, T, H, hd); k/v_pool: (P, ps, Kv, hd); pos_pool: (P, ps);
-    block_tables: (B, n_blocks) int32 page ids, -1 unmapped. Returns
-    (B, T, H, hd)."""
+    q: (B, T, H, hd); k/v_pool: (P, ps, Kv * hd), heads folded; pos_pool:
+    (P, ps); block_tables: (B, n_blocks) int32 page ids, -1 unmapped.
+    Returns (B, T, H, hd)."""
     B, nb = block_tables.shape
-    ps = k_pool.shape[1]
+    ps, hd = k_pool.shape[1], q.shape[-1]
     pages = jnp.where(block_tables >= 0, block_tables, 0)
-    k = k_pool[pages].reshape(B, nb * ps, *k_pool.shape[2:])
-    v = v_pool[pages].reshape(B, nb * ps, *v_pool.shape[2:])
+    k = k_pool[pages].reshape(B, nb * ps, -1, hd)
+    v = v_pool[pages].reshape(B, nb * ps, -1, hd)
     kpos = jnp.where(block_tables[..., None] >= 0, pos_pool[pages], -1)
     return decode_gqa_ref(q, k, v, kpos.reshape(B, nb * ps), q_pos,
                           window=window)

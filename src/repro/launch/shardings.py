@@ -130,10 +130,10 @@ def serving_state_shardings(gstate, mesh: Mesh):
 
     def cache_node(node):
         if isinstance(node, PagedKVCache):
-            # trailing dims are (pages, ps, Kv, hd) / (pages, ps); a
+            # trailing dims are (pages, ps, Kv * hd) / (pages, ps); a
             # leading scan-repeat dim may or may not be present
             pool = _shard_one_axis(mesh, node.k_pool.shape,
-                                   node.k_pool.ndim - 4, dp)
+                                   node.k_pool.ndim - 3, dp)
             return PagedKVCache(
                 k_pool=pool, v_pool=pool,
                 pos=_shard_one_axis(mesh, node.pos.shape,

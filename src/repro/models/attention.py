@@ -15,8 +15,9 @@ on stored positions, which makes a ring buffer (sliding window, ``S = window``)
 and a linear cache (``S = max_len``) the same code path.
 
 Paged variant (serving): ``PagedKVCache`` replaces the per-row ``(B, S)``
-reservation with a global page pool ``(n_pages, page_size, n_kv, head_dim)``
-plus a per-row block table ``(B, n_blocks)`` of page ids (-1 = unmapped).
+reservation with a global page pool ``(n_pages, page_size, n_kv * head_dim)``
+(heads folded into one lane-dense minor axis) plus a per-row block table
+``(B, n_blocks)`` of page ids (-1 = unmapped).
 Rows of one request share read-only committed pages (the host allocator in
 ``repro.core.session.PageAllocator`` copy-on-writes the draft-boundary page),
 so HBM scales with *live tokens*, not ``n_rows * max_len``. Page 0 is a
@@ -156,16 +157,25 @@ class PagedKVCache:
       - attention masks on STORED positions, so which physical page backs
         a block never affects output — aliased and privately-owned reads
         are bitwise identical.
+
+    Storage: a page holds ``ps`` token rows of ``n_kv * head_dim`` values,
+    the heads folded into one minor axis. At a head_dim below the 128-lane
+    tile (mt-retro: 8 heads x 32) an unfolded pool would be padded to 128
+    lanes, or kept with pages minor-most and relaid out for every scatter;
+    folded, it is dense at any head_dim. Heads are split again only in the
+    gathered per-row view (``paged_view``). Model caches stack one pool per
+    layer, ``(R, P, ps, n_kv * head_dim)``, and decode steps write and read
+    it at ``[layer, page, slot]`` in place (``cached_attention``).
     """
 
-    k_pool: jnp.ndarray        # (P, ps, n_kv, head_dim)
-    v_pool: jnp.ndarray        # (P, ps, n_kv, head_dim)
+    k_pool: jnp.ndarray        # (P, ps, n_kv * head_dim)
+    v_pool: jnp.ndarray        # (P, ps, n_kv * head_dim)
     pos: jnp.ndarray           # (P, ps) int32, absolute position stored, -1 empty
     block_tables: jnp.ndarray  # (B, n_blocks) int32 page id, -1 unmapped
 
     @property
     def page_size(self) -> int:
-        return self.k_pool.shape[-3]
+        return self.pos.shape[-1]
 
     @property
     def n_blocks(self) -> int:
@@ -189,51 +199,61 @@ def init_paged_kv_cache(cfg: ModelConfig, batch: int, max_len: int, *,
     if n_pages < 2:
         raise ValueError("n_pages must be >= 2 (page 0 is the trash page)")
     return PagedKVCache(
-        k_pool=jnp.zeros((n_pages, page_size, n_kv, cfg.head_dim), dtype),
-        v_pool=jnp.zeros((n_pages, page_size, n_kv, cfg.head_dim), dtype),
+        k_pool=jnp.zeros((n_pages, page_size, n_kv * cfg.head_dim), dtype),
+        v_pool=jnp.zeros((n_pages, page_size, n_kv * cfg.head_dim), dtype),
         pos=jnp.full((n_pages, page_size), -1, jnp.int32),
         block_tables=jnp.full((batch, n_blocks), -1, jnp.int32),
     )
 
 
-def _lookup_pages(cache: PagedKVCache, positions):
+def _lookup_pages(block_tables, positions, ps: int):
     """positions (B, T) -> (page (B, T), slot (B, T), mapped (B, T))."""
-    ps, nb = cache.page_size, cache.n_blocks
+    nb = block_tables.shape[-1]
     blocks = (positions // ps) % nb
-    b_idx = jnp.arange(cache.block_tables.shape[0])[:, None]
-    page = cache.block_tables[b_idx, blocks]
+    b_idx = jnp.arange(block_tables.shape[0])[:, None]
+    page = block_tables[b_idx, blocks]
     mapped = (page >= 0) & (positions >= 0)
     return jnp.where(mapped, page, TRASH_PAGE), positions % ps, mapped
 
 
-def _write_cache_paged(cache: PagedKVCache, k_new, v_new, positions
+def _write_cache_paged(cache: PagedKVCache, layer, k_new, v_new, positions
                        ) -> PagedKVCache:
-    """Scatter new K/V through the block table; positions: (B, T). Invalid
-    targets (position -1 or unmapped block) go to the trash page with stored
-    position -1 — unreadable, exactly like the dense pad convention."""
-    page, slot, mapped = _lookup_pages(cache, positions)
+    """Scatter new K/V (B, T, n_kv, hd) into layer ``layer`` of the stacked
+    pool through the block table; positions: (B, T). One scatter per pool
+    at ``[layer, page, slot]``, heads folded: the donated pool is updated
+    in place. Invalid targets (position -1 or unmapped block) go to the
+    trash page with stored position -1 — unreadable, exactly like the
+    dense pad convention."""
+    B, T = positions.shape
+    page, slot, mapped = _lookup_pages(cache.block_tables[layer], positions,
+                                       cache.page_size)
     store_pos = jnp.where(mapped, positions, -1).astype(jnp.int32)
     return dataclasses.replace(
         cache,
-        k_pool=cache.k_pool.at[page, slot].set(k_new.astype(cache.k_pool.dtype)),
-        v_pool=cache.v_pool.at[page, slot].set(v_new.astype(cache.v_pool.dtype)),
-        pos=cache.pos.at[page, slot].set(store_pos),
+        k_pool=cache.k_pool.at[layer, page, slot].set(
+            k_new.reshape(B, T, -1).astype(cache.k_pool.dtype)),
+        v_pool=cache.v_pool.at[layer, page, slot].set(
+            v_new.reshape(B, T, -1).astype(cache.v_pool.dtype)),
+        pos=cache.pos.at[layer, page, slot].set(store_pos),
     )
 
 
-def paged_view(cache: PagedKVCache):
-    """Materialize the dense per-row view (k, v, kpos) the attention math
-    expects: (B, n_blocks*ps, n_kv, hd) x2 + (B, n_blocks*ps) positions.
-    Unmapped blocks read the trash page but are masked to position -1. This
-    is the XLA reference read path; the Pallas kernel
-    (``repro.kernels.decode_gqa.paged_decode_gqa_attention``) walks the block
-    table instead and never materializes the gather."""
-    B, nb = cache.block_tables.shape
+def paged_view(cache: PagedKVCache, layer, n_kv: int):
+    """Materialize layer ``layer``'s dense per-row view (k, v, kpos) the
+    attention math expects: (B, n_blocks*ps, n_kv, hd) x2 + (B,
+    n_blocks*ps) positions, one gather per pool at ``[layer, pages]``; the
+    heads are split here. Unmapped blocks read the trash page but are
+    masked to position -1. This is the XLA reference read path; the Pallas
+    kernel (``repro.kernels.decode_gqa.paged_decode_gqa_attention``) walks
+    the block table instead and never materializes the gather."""
+    block_tables = cache.block_tables[layer]
+    B, nb = block_tables.shape
     ps = cache.page_size
-    pages = jnp.where(cache.block_tables >= 0, cache.block_tables, TRASH_PAGE)
-    k = cache.k_pool[pages].reshape(B, nb * ps, *cache.k_pool.shape[2:])
-    v = cache.v_pool[pages].reshape(B, nb * ps, *cache.v_pool.shape[2:])
-    kpos = jnp.where(cache.block_tables[..., None] >= 0, cache.pos[pages], -1)
+    pages = jnp.where(block_tables >= 0, block_tables, TRASH_PAGE)
+    k = cache.k_pool[layer, pages].reshape(B, nb * ps, n_kv, -1)
+    v = cache.v_pool[layer, pages].reshape(B, nb * ps, n_kv, -1)
+    kpos = jnp.where(block_tables[..., None] >= 0, cache.pos[layer, pages],
+                     -1)
     return k, v, kpos.reshape(B, nb * ps)
 
 
@@ -450,17 +470,20 @@ def commit_verified_kv(cache: KVCache, k_new, v_new, take_idx, positions,
     )
 
 
-def cached_attention(p: dict, cfg: ModelConfig, x, cache, positions,
-                     ) -> tuple[jnp.ndarray, Any]:
+def cached_attention(p: dict, cfg: ModelConfig, x, cache, positions, *,
+                     layer=None) -> tuple[jnp.ndarray, Any]:
     """Cached causal decode (and prefill-into-cache), dense or paged.
 
     x: (B, T, d) new tokens; positions: (B, T) absolute positions of those
     tokens (rows may differ — the speculative decoder relies on this).
     Pad-token convention: ``positions == -1`` marks invalid tokens; their K/V
     land in a throwaway slot with stored position -1, which every query masks.
-    ``cache`` is a ``KVCache`` or a ``PagedKVCache`` — masking semantics are
-    identical, so the two produce the same output for the same stored tokens.
-    Returns output (B, T, d) and the updated cache.
+    ``cache`` is one layer's ``KVCache``, or a ``PagedKVCache`` stacked over
+    the model's layers with ``layer`` (an int32 scalar, may be traced)
+    naming this layer: the paged pool is written and read at that layer in
+    place (the XLA read path never slices it out). Masking semantics are
+    identical, so the two produce the same output for the same stored
+    tokens. Returns output (B, T, d) and the updated cache.
     """
     B, T = x.shape[:2]
     q, k_new, v_new = _project_qkv(p, cfg, x, x, cross=False)
@@ -468,18 +491,18 @@ def cached_attention(p: dict, cfg: ModelConfig, x, cache, positions,
         q = apply_rope(q, positions, cfg.rope_theta)
         k_new = apply_rope(k_new, positions, cfg.rope_theta)
     if isinstance(cache, PagedKVCache):
-        cache = _write_cache_paged(cache, k_new, v_new, positions)
+        cache = _write_cache_paged(cache, layer, k_new, v_new, positions)
         if _PAGED_KERNEL:
             # Lazy import: models must not depend on the kernels package
             # unless the fast path is actually enabled.
             from repro.kernels.decode_gqa import paged_decode_gqa_attention
             out = paged_decode_gqa_attention(
-                q, cache.k_pool, cache.v_pool, cache.pos,
-                cache.block_tables, positions,
+                q, cache.k_pool[layer], cache.v_pool[layer], cache.pos[layer],
+                cache.block_tables[layer], positions,
                 window=cfg.sliding_window)
             return dense(p["wo"], out.reshape(B, T, -1)), cache
         with jax.named_scope("page_view"):
-            k, v, kpos = paged_view(cache)
+            k, v, kpos = paged_view(cache, layer, k_new.shape[2])
     else:
         cache = _write_cache(cache, k_new, v_new, positions)
         k, v, kpos = cache.k, cache.v, cache.pos

@@ -153,7 +153,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, memory=None,
     leaf), so batch-row expansion/gather/scatter ops carry each row's mask
     along and ``decode_step`` needs no closed-over mask.
     ``paged``: ``(n_pages, page_size)`` — allocate the self-attn cache as a
-    ``PagedKVCache`` (one pool per decoder layer) instead of dense rows; the
+    ``PagedKVCache`` (one pool per decoder layer, stacked to ``(n_layers,
+    n_pages, page_size, n_kv_heads * head_dim)``) instead of dense rows; the
     caller owns page mapping (``repro.core.session.PageAllocator``). The
     cross K/V stays dense: it is fixed-size per request and written once at
     admission.
@@ -193,18 +194,26 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions, *,
     encodings (DESIGN.md §2). When no explicit ``memory_mask`` is passed the
     per-row mask stored in the cache (if any) applies. Each layer's parts
     run under the named scopes ``self_attn``, ``cross_attn`` and ``ffn``,
-    the output projection under ``lm_head``.
+    the output projection under ``lm_head``. A paged self-attention pool is
+    threaded through the layer scan whole and updated in place; no layer's
+    pool is copied out of the stack or back.
     """
     if memory_mask is None and "mmask" in cache:
         memory_mask = cache["mmask"][0]
     x = _embed_pos(params, cfg, tokens, positions)
+    # A paged pool rides in the carry, written and read at [layer, ...] in
+    # place; a dense cache is sliced per layer (scan xs/ys).
+    paged = isinstance(cache["self"], attn_mod.PagedKVCache)
+    pool = cache["self"] if paged else None
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
 
-    def body(h, xs):
-        p, c_self, c_cross = xs
+    def body(carry, xs):
+        h, pool = carry
+        p, c_self, c_cross, layer = xs
         with jax.named_scope("self_attn"):
             a, c_self = cached_attention(
                 p["self_attn"], cfg, apply_norm(p["norm1"], h, cfg.norm),
-                c_self, positions)
+                pool if paged else c_self, positions, layer=layer)
             h = h + a
         with jax.named_scope("cross_attn"):
             c = attn_mod.cached_cross_attention(
@@ -212,15 +221,17 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions, *,
                 c_cross, memory_mask=memory_mask)
             h = h + c
         with jax.named_scope("ffn"):
-            f = ffn(p["ffn"], apply_norm(p["norm2"], h, cfg.norm))
-            return h + f, c_self
+            h = h + ffn(p["ffn"], apply_norm(p["norm2"], h, cfg.norm))
+        return ((h, c_self), None) if paged else ((h, None), c_self)
 
-    x, new_self = jax.lax.scan(
-        body, x, (params["dec_blocks"], cache["self"], cache["cross"]))
+    dense_self = None if paged else cache["self"]
+    (x, pool), new_self = jax.lax.scan(
+        body, (x, pool),
+        (params["dec_blocks"], dense_self, cache["cross"], layers))
     with jax.named_scope("lm_head"):
         x = apply_norm(params["dec_norm"], x, cfg.norm)
         logits = x @ params["lm_head"]["w_vocab"]
-    new_cache = {"self": new_self, "cross": cache["cross"]}
+    new_cache = {"self": pool if paged else new_self, "cross": cache["cross"]}
     if "mmask" in cache:
         new_cache["mmask"] = cache["mmask"]
     return logits, new_cache

@@ -118,7 +118,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32,
 
     ``paged``: ``(n_pages, page_size)`` — allocate every "attn" position's
     self-attention cache as a ``PagedKVCache`` (one pool per position,
-    stacked over repeats) instead of dense rows; the caller owns page
+    stacked over repeats to ``(n_repeats, n_pages, page_size, n_kv_heads *
+    head_dim)``) instead of dense rows; the caller owns page
     mapping (``repro.core.session.PageAllocator``). All paged positions
     share one page-id space: the allocator keeps their block tables
     identical, so a page id addresses the same logical block in every
@@ -238,8 +239,9 @@ def apply_norm_block(p, x, cfg: ModelConfig):
 
 
 def _block_apply(kind: str, ffn_kind: str, p, cfg: ModelConfig, x, cache,
-                 dctx: DecodeContext):
-    """One layer. Returns (x, aux_losses, new_cache)."""
+                 dctx: DecodeContext, layer=None):
+    """One layer. Returns (x, new_cache, aux_losses). A paged "attn" cache
+    is the whole stack over repeats, addressed at repeat ``layer``."""
     aux: dict = {}
     if kind == "rwkv":
         if dctx.mode == "decode":
@@ -277,7 +279,8 @@ def _block_apply(kind: str, ffn_kind: str, p, cfg: ModelConfig, x, cache,
                           (jnp.arange(h.shape[1]) < dctx.lengths[:, None]))
             new_cache = None
         else:
-            a, new_cache = cached_attention(p["attn"], cfg, h, cache, dctx.positions)
+            a, new_cache = cached_attention(p["attn"], cfg, h, cache,
+                                            dctx.positions, layer=layer)
         x = x + a
     elif kind == "xattn":
         if dctx.mode == "decode":
@@ -325,24 +328,36 @@ SCAN_UNROLL: int | bool = 1
 def _run_stack(params, cfg: ModelConfig, x, cache, dctx: DecodeContext,
                *, remat: bool = False):
     aux_keys = ("moe_aux_loss", "moe_z_loss") if "moe" in cfg.ffn_pattern else ()
+    # Paged pools ride in the carry, written and read at [repeat, ...] in
+    # place; every other cache is sliced per repeat (scan xs/ys).
+    caches = (None,) * len(cfg.layer_pattern) if cache is None else cache
+    paged = tuple(isinstance(c, attn_mod.PagedKVCache) for c in caches)
+    pools = tuple(c if p else None for c, p in zip(caches, paged))
+    sliced = tuple(None if p else c for c, p in zip(caches, paged))
 
-    def repeat_body(h, xs):
-        p_tuple, c_tuple = xs
-        new_caches = []
+    def repeat_body(carry, xs):
+        h, pools = carry
+        p_tuple, c_tuple, rep = xs
+        new_pools, new_caches = [], []
         aux_sum = {k: jnp.float32(0) for k in aux_keys}
         for i, kind in enumerate(cfg.layer_pattern):
-            c_i = None if c_tuple is None else c_tuple[i]
+            c_i = pools[i] if paged[i] else c_tuple[i]
             h, nc, aux = _block_apply(kind, cfg.ffn_pattern[i], p_tuple[i],
-                                      cfg, h, c_i, dctx)
+                                      cfg, h, c_i, dctx, layer=rep)
             h = shard_ctx.constrain_activation(h)
-            new_caches.append(nc)
+            new_pools.append(nc if paged[i] else None)
+            new_caches.append(None if paged[i] else nc)
             for k in aux_keys:
                 aux_sum[k] = aux_sum[k] + aux.get(k, 0.0)
-        return h, (tuple(new_caches), aux_sum)
+        return (h, tuple(new_pools)), (tuple(new_caches), aux_sum)
 
     body = jax.checkpoint(repeat_body) if remat else repeat_body
-    x, (new_cache, aux_per_rep) = jax.lax.scan(body, x, (params["blocks"], cache),
-                                               unroll=SCAN_UNROLL)
+    reps = jnp.arange(cfg.n_repeats, dtype=jnp.int32)
+    (x, pools), (new_sliced, aux_per_rep) = jax.lax.scan(
+        body, (x, pools), (params["blocks"], sliced, reps),
+        unroll=SCAN_UNROLL)
+    new_cache = tuple(pl if p else c
+                      for pl, c, p in zip(pools, new_sliced, paged))
     aux = {k: jnp.sum(v) for k, v in aux_per_rep.items()}
     return x, new_cache, aux
 

@@ -164,17 +164,28 @@ def _paged_model():
     return cfg, tr.init(jax.random.PRNGKey(0), cfg)
 
 
-@pytest.mark.parametrize("mode", ["greedy", "speculative"])
+@pytest.mark.parametrize("mode", ["greedy", "speculative", "beam",
+                                  "speculative_beam"])
 def test_decoder_paged_matches_dense(prompts, mode):
+    """Paged decoder-only serving is token-identical to dense in every
+    mode; the beam modes reorder rows over shared pages and copy-on-write
+    the draft-boundary page every step, in every layer of the pool."""
     cfg, params = _paged_model()
-    dense = _engine(cfg, params, mode)
-    paged = _engine(cfg, params, mode, paged=True, page_size=8)
+    kw = dict(n_beams=3) if "beam" in mode else {}
+    dense = _engine(cfg, params, mode, **kw)
+    paged = _engine(cfg, params, mode, paged=True, page_size=8, **kw)
+    pool = paged.scheduler.state.cache[0].k_pool
+    assert pool.shape[0] == cfg.n_repeats
+    assert pool.shape[2:] == (8, cfg.n_kv_heads * cfg.head_dim)
     rd = [dense.submit(p) for p in prompts]
     rp = [paged.submit(p) for p in prompts]
     res_d, res_p = dense.serve(), paged.serve()
     for a, b in zip(rd, rp):
         np.testing.assert_array_equal(np.asarray(res_d[a].tokens),
                                       np.asarray(res_p[b].tokens))
+        np.testing.assert_allclose(np.asarray(res_d[a].logprobs),
+                                   np.asarray(res_p[b].logprobs),
+                                   rtol=1e-5, atol=1e-5)
     paged.allocator.check()
     fp = paged.cache_footprint()
     assert fp["peak_bytes"] <= fp["capacity_bytes"]

@@ -84,10 +84,12 @@ def test_decode_gqa_ring_buffer():
 
 def _random_paged_cache(rng, B, P, ps, nb, Kv, hd, *, n_mapped, dtype):
     """Rows map ``n_mapped`` distinct pages each (prefix-contiguous blocks),
-    with ragged fill levels; the rest of the table is unmapped (-1)."""
+    with ragged fill levels; the rest of the table is unmapped (-1). Pages
+    hold (ps, Kv * hd) rows, heads folded, as ``PagedKVCache`` stores
+    them."""
     keys = jax.random.split(jax.random.PRNGKey(int(rng.integers(1 << 30))), 2)
-    k_pool = jax.random.normal(keys[0], (P, ps, Kv, hd), dtype)
-    v_pool = jax.random.normal(keys[1], (P, ps, Kv, hd), dtype)
+    k_pool = jax.random.normal(keys[0], (P, ps, Kv * hd), dtype)
+    v_pool = jax.random.normal(keys[1], (P, ps, Kv * hd), dtype)
     bt = np.full((B, nb), -1, np.int32)
     pages = rng.permutation(np.arange(1, P))[:B * n_mapped]
     bt[:, :n_mapped] = pages.reshape(B, n_mapped)
@@ -146,10 +148,10 @@ def test_paged_decode_gqa_matches_dense_kernel():
     pages = rng.permutation(np.arange(1, B * nb + 1))
     bt = jnp.asarray(pages.reshape(B, nb).astype(np.int32))
     P = B * nb + 1
-    k_pool = jnp.zeros((P, ps, Kv, hd)).at[bt.reshape(-1)].set(
-        kc.reshape(B * nb, ps, Kv, hd))
-    v_pool = jnp.zeros((P, ps, Kv, hd)).at[bt.reshape(-1)].set(
-        vc.reshape(B * nb, ps, Kv, hd))
+    k_pool = jnp.zeros((P, ps, Kv * hd)).at[bt.reshape(-1)].set(
+        kc.reshape(B * nb, ps, Kv * hd))
+    v_pool = jnp.zeros((P, ps, Kv * hd)).at[bt.reshape(-1)].set(
+        vc.reshape(B * nb, ps, Kv * hd))
     pos_pool = jnp.full((P, ps), -1, jnp.int32).at[bt.reshape(-1)].set(
         k_pos.reshape(B * nb, ps))
     dense = decode_gqa_attention(q, kc, vc, k_pos, q_pos, bk=ps)

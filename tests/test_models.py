@@ -157,43 +157,125 @@ def test_seq2seq_decode_matches_full():
                                    rtol=2e-4, atol=2e-4)
 
 
+def _self_node(cache):
+    """The self-attention cache node of a seq2seq dict or a decoder-only
+    per-pattern-position tuple."""
+    return cache["self"] if isinstance(cache, dict) else cache[0]
+
+
+def _private_window_pages(cache, rows_blocks, next_page):
+    """Copy-on-write as the megastep's page plan does it: map each (row,
+    block) of the coming write window to a fresh page, copying the current
+    page's contents (every layer) through ``apply_page_plan``. Returns the
+    cache and the next unused page id."""
+    from repro.core.session import DevicePagePlan, apply_page_plan
+    bt = np.asarray(_self_node(cache).block_tables[0])
+    rows = np.asarray([r for r, _ in rows_blocks], np.int32)
+    blocks = np.asarray([b for _, b in rows_blocks], np.int32)
+    cur = bt[rows, blocks]
+    new = np.arange(next_page, next_page + len(rows), dtype=np.int32)
+    plan = DevicePagePlan(
+        exhausted=jnp.bool_(False), n_free=jnp.int32(0), need_by_group=None,
+        rows=jnp.asarray(rows), blocks=jnp.asarray(blocks),
+        need=jnp.ones(len(rows), bool), copy=jnp.asarray(cur >= 0),
+        cur=jnp.asarray(cur), new=jnp.asarray(new))
+    return apply_page_plan(cache, plan), next_page + len(rows)
+
+
+def _paged_matches_dense(step, dense, paged, n_rows, steps, chunk, ps):
+    """Feed ``steps`` chunks of ``chunk`` tokens through a dense and a paged
+    cache. Between steps the rows are reordered as beam search does (the
+    paged rows then alias pages) and every row's next write window gets
+    private pages by copy-on-write. Each step's logits must match, and the
+    layer-``l`` slice of the stacked pool must hold, heads folded, exactly
+    the K/V the dense cache holds for layer ``l``."""
+    from repro.core.tree_batch import gather_rows
+    from repro.models.attention import PagedKVCache
+    sc = _self_node(paged)
+    assert isinstance(sc, PagedKVCache)
+    R, n_blocks = sc.k_pool.shape[0], sc.block_tables.shape[-1]
+    k_dense = _self_node(dense).k
+    assert sc.k_pool.shape[2:] == (ps, k_dense.shape[-2] * k_dense.shape[-1])
+    # map every block of every row to a distinct page up front
+    bt = jnp.arange(1, n_rows * n_blocks + 1,
+                    dtype=jnp.int32).reshape(n_rows, n_blocks)
+    sc = dataclasses.replace(
+        sc, block_tables=jnp.broadcast_to(bt, sc.block_tables.shape))
+    paged = ({**paged, "self": sc} if isinstance(paged, dict)
+             else (sc,) + tuple(paged[1:]))
+    next_page = n_rows * n_blocks + 1
+    rng = np.random.default_rng(0)
+    for i in range(steps):
+        start = i * chunk
+        positions = (jnp.arange(chunk) + start)[None, :].repeat(n_rows, 0)
+        tokens = jnp.asarray(rng.integers(4, 40, (n_rows, chunk)), jnp.int32)
+        ld, dense = step(dense, tokens, positions)
+        lp, paged = step(paged, tokens, positions)
+        np.testing.assert_allclose(np.asarray(lp), np.asarray(ld),
+                                   rtol=2e-5, atol=2e-5)
+        d, p = _self_node(dense), _self_node(paged)
+        pos = np.arange(start + chunk)
+        tab = np.asarray(p.block_tables[0])
+        for layer in range(R):
+            for kv_d, kv_p in ((d.k, p.k_pool), (d.v, p.v_pool)):
+                want = np.asarray(kv_d[layer][:, pos])  # (B, S, n_kv, hd)
+                got = np.asarray(kv_p[layer])[tab[:, pos // ps], pos % ps]
+                np.testing.assert_allclose(
+                    got, want.reshape(got.shape), rtol=2e-5, atol=2e-5)
+        # beam reorder: rows now share their parents' pages
+        src = jnp.asarray(rng.integers(0, n_rows, n_rows), jnp.int32)
+        dense, paged = gather_rows(dense, src), gather_rows(paged, src)
+        nxt = start + chunk + np.arange(chunk)
+        window = [(r, int(b)) for r in range(n_rows)
+                  for b in np.unique(nxt // ps)]
+        paged, next_page = _private_window_pages(paged, window, next_page)
+    return next_page
+
+
 def test_seq2seq_paged_decode_matches_dense():
     """The same decode_step chunks through a paged self-attn cache (block
-    tables mapped by hand, private pages per row) produce logits identical
-    to the dense cache — the models-layer half of the paged/dense
-    token-identity contract (the session/engine half lives in
-    tests/test_session.py)."""
+    tables mapped by hand, then beam reorders and copy-on-write pages
+    between steps) produce logits identical to the dense cache, and each
+    layer's slice of the stacked pool holds that layer's K/V — the
+    models-layer half of the paged/dense token-identity contract (the
+    session/engine half lives in tests/test_session.py)."""
     from repro.configs.mt import tiny_config
-    from repro.models.attention import PagedKVCache
     cfg = tiny_config(48, depth=2, d_model=64)
     key = jax.random.PRNGKey(5)
     params = s2s.init(key, cfg)
-    B, S, T, ps = 2, 14, 10, 4
+    B, S, ps, max_len, chunk, steps = 3, 14, 4, 32, 3, 5
     src = jax.random.randint(key, (B, S), 4, cfg.vocab_size)
-    tgt = jax.random.randint(jax.random.PRNGKey(6), (B, T), 4, cfg.vocab_size)
     memory, src_mask = s2s.encode(params, cfg, src)
+    n_blocks = max_len // ps
+    dense = s2s.init_cache(cfg, B, max_len=max_len, memory=memory,
+                           params=params, memory_mask=src_mask)
+    paged = s2s.init_cache(cfg, B, max_len=max_len, memory=memory,
+                           params=params, memory_mask=src_mask,
+                           paged=(B * n_blocks + 1 + B * 2 * steps, ps))
 
-    dense = s2s.init_cache(cfg, B, max_len=32, memory=memory, params=params)
-    n_blocks = 32 // ps
-    paged = s2s.init_cache(cfg, B, max_len=32, memory=memory, params=params,
-                           paged=(B * n_blocks + 1, ps))
-    sc = paged["self"]
-    assert isinstance(sc, PagedKVCache)
-    # map every block of every row to a distinct page up front
-    bt = jnp.arange(1, B * n_blocks + 1, dtype=jnp.int32).reshape(B, n_blocks)
-    paged["self"] = dataclasses.replace(
-        sc, block_tables=jnp.broadcast_to(bt, sc.block_tables.shape))
+    def step(cache, tokens, positions):
+        return s2s.decode_step(params, cfg, cache, tokens, positions)
 
-    for start in range(0, T, 4):
-        chunk = tgt[:, start: start + 4]
-        Tc = chunk.shape[1]
-        positions = (jnp.arange(Tc) + start)[None, :].repeat(B, 0)
-        ld, dense = s2s.decode_step(params, cfg, dense, chunk, positions,
-                                    memory_mask=src_mask)
-        lp, paged = s2s.decode_step(params, cfg, paged, chunk, positions,
-                                    memory_mask=src_mask)
-        np.testing.assert_allclose(np.asarray(lp), np.asarray(ld),
-                                   rtol=2e-5, atol=2e-5)
+    _paged_matches_dense(step, dense, paged, B, steps, chunk, ps)
+
+
+def test_decoder_only_paged_decode_matches_dense():
+    """The decoder-only half of the same contract (GQA: 3 query heads over
+    one KV head, rope): paged decode through ``transformer.decode_step``
+    with beam reorders and copy-on-write pages between steps matches the
+    dense cache step for step, layer for layer."""
+    cfg = get_config("smollm-135m", reduced=True)
+    params = tr.init(jax.random.PRNGKey(3), cfg)
+    B, ps, max_len, chunk, steps = 3, 4, 32, 3, 5
+    n_blocks = max_len // ps
+    dense = tr.init_cache(cfg, B, max_len)
+    paged = tr.init_cache(cfg, B, max_len,
+                          paged=(B * n_blocks + 1 + B * 2 * steps, ps))
+
+    def step(cache, tokens, positions):
+        return tr.decode_step(params, cfg, cache, tokens, positions)
+
+    _paged_matches_dense(step, dense, paged, B, steps, chunk, ps)
 
 
 def test_sliding_window_variant_matches_full_within_window():

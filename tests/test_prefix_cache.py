@@ -259,8 +259,8 @@ def _plan_fixture(n_pages=12, table=None, pos=0, active=True):
             bt[r, :len(row)] = row
     # session-level paged nodes stack layers on a leading axis (1 here)
     cache = PagedKVCache(
-        k_pool=jnp.zeros((1, n_pages, ps, 1, 4)),
-        v_pool=jnp.zeros((1, n_pages, ps, 1, 4)),
+        k_pool=jnp.zeros((1, n_pages, ps, 4)),
+        v_pool=jnp.zeros((1, n_pages, ps, 4)),
         pos=jnp.full((1, n_pages, ps), -1, jnp.int32),
         block_tables=jnp.asarray(bt)[None])
     state = init_state(spec, None)

@@ -150,12 +150,18 @@ PAGED_MODES = [
 @pytest.mark.parametrize("mode,kw", PAGED_MODES)
 def test_paged_matches_dense_all_modes(toy, mode, kw):
     """Acceptance criterion: the paged cache is a pure memory-layout change
-    — token-identical outputs (and beam log-probs) in all four modes."""
-    ds, _, _ = toy
+    — token-identical outputs (and beam log-probs) in all four modes. The
+    pool is one lane-dense stack, (layers, pages, page_size, n_kv *
+    head_dim), that every step writes in place; the beam modes reorder
+    rows over shared pages and copy-on-write pages each step."""
+    ds, cfg, _ = toy
     queries = [ds.pair(i)[0] for i in range(4)]
     _, dense = _engines(toy, mode=mode, n_slots=2, **kw)
     _, paged = _engines(toy, mode=mode, n_slots=2, paged=True, page_size=8,
                         **kw)
+    pool = paged.scheduler.state.cache["self"].k_pool
+    assert pool.shape == (cfg.n_layers, paged._paged_geometry()[0], 8,
+                          cfg.n_kv_heads * cfg.head_dim)
     if mode in ("greedy", "speculative"):
         a, b = dense.predict(queries), paged.predict(queries)
         assert [p.smiles[0] for p in a] == [p.smiles[0] for p in b]

@@ -3,9 +3,10 @@
 The Pallas decode kernels and one speculative-verify decode step, at the
 published widths of the models the engine serves, must pass Mosaic's
 tiling rules, lower to a real kernel (``tpu_custom_call``, not the
-interpreter) and fit one chip's 16 GB. Nothing runs, so these say nothing
-about results or times; they catch refused block shapes and excess memory
-at no chip cost.
+interpreter) and fit one chip's 16 GB. The chip benchmark's megastep must
+keep its page pool in place. Nothing runs, so these say nothing about
+results or times; they catch refused block shapes, excess memory and
+whole-buffer copies at no chip cost.
 
 Only one process at a time may load the TPU runtime, so the topology is
 described inside a module fixture, never while a module is imported, and
@@ -13,6 +14,7 @@ every test of this kind lives in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,10 @@ from repro.serving import EngineConfig, StreamingEngine
 V5E_HBM_BYTES = 16 * 10**9
 ECFG = EngineConfig()                       # the paper's serving shapes
 PAGE = 16
+# the chip benchmark's mt-retro cell (benchmarks/chip/configs/mt-retro.json)
+CELL = EngineConfig(mode="speculative_beam", n_slots=4, n_beams=5,
+                    n_drafts=25, draft_len=10, max_new=96, max_src=128,
+                    paged=True, page_size=PAGE)
 
 
 @pytest.fixture(scope="module")
@@ -104,7 +110,7 @@ def test_paged_decode_kernel_compiles(one_chip, name, T):
 
     hlo = _compile(
         lambda *a: decode_ops.paged_decode_gqa_attention(*a, interpret=False),
-        s((B, T, H, hd)), s((P, PAGE, Kv, hd)), s((P, PAGE, Kv, hd)),
+        s((B, T, H, hd)), s((P, PAGE, Kv * hd)), s((P, PAGE, Kv * hd)),
         s((P, PAGE), jnp.int32), s((B, n_blocks), jnp.int32),
         s((B, T), jnp.int32))
     assert "tpu_custom_call" in hlo
@@ -183,3 +189,114 @@ def test_mt_retro_sharded_megastep_compiles_for_four_chips(topo,
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < V5E_HBM_BYTES)
     assert "all-reduce" in compiled.as_text()
+
+
+# -- reading a compiled HLO module ------------------------------------------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%(\S+) = (.*)$")
+_CALLS = re.compile(r"(?:calls|body|condition|to_apply|true_computation|"
+                    r"false_computation)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
+# a branch made only of these passes its operands through unchanged
+_PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "copy",
+                 "copy-start", "copy-done", "bitcast", "constant"}
+
+
+def _shape_and_op(rest: str) -> tuple[str, str]:
+    """Split ``<shape> <opcode>(...)`` of an instruction line; a tuple
+    shape is parenthesised."""
+    if rest.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape, tail = rest[:end + 1], rest[end + 2:]
+    else:
+        shape, _, tail = rest.partition(" ")
+    return shape, tail.partition("(")[0]
+
+
+def _computations(hlo: str) -> tuple[dict, str]:
+    """{computation: [(name, is_root, shape, opcode, line)]}, entry name."""
+    comps, entry, cur = {}, None, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+            entry = m.group(1) if line.startswith("ENTRY") else entry
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and cur is not None:
+            shape, op = _shape_and_op(m.group(3))
+            cur.append((m.group(2), bool(m.group(1)), shape, op, line))
+    return comps, entry
+
+
+def _buffers_moved(hlo: str, ops: set, n_elements: set) -> list:
+    """Instructions of opcode in ``ops`` (a fusion counts as its root's
+    opcode) whose output holds an array of one of ``n_elements``, on every
+    path from the entry except conditional branches that only pass their
+    operands through."""
+    comps, entry = _computations(hlo)
+
+    def root_op(name):
+        return next(op for _, root, _, op, _ in comps[name] if root)
+
+    seen, todo, found = set(), [entry], []
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for name, _, shape, op, line in comps[comp]:
+            called = _CALLS.findall(line)
+            branches = _BRANCHES.search(line)
+            if branches:
+                called += [b.strip().lstrip("%")
+                           for b in branches.group(1).split(",")]
+            if op == "conditional":
+                called = [c for c in called if not all(
+                    i[3] in _PASS_THROUGH for i in comps[c])]
+            if op == "fusion":
+                op, called = root_op(called[0]), []
+            todo += called
+            sizes = {int(np.prod([int(d) for d in dims.split(",") if d]))
+                     for dims in _ARRAY.findall(shape)}
+            if op in ops and sizes & n_elements:
+                found.append(f"{comp}: {name} {op} {shape[:60]}")
+    return found
+
+
+def test_mt_retro_megastep_keeps_page_pool_in_place(one_chip):
+    """The benchmark cell's one-chip megastep (mt-retro, speculative beam,
+    four slots at the paper's shapes): the self-attention page pool is
+    written in place by layer-indexed scatters. Outside the pool-exhausted
+    identity branch no copy, dynamic-slice or dynamic-update-slice makes a
+    buffer the size of a layer's pool or of the stacked pool, and the
+    step's temporaries stay under 1.5 GB (6.0 GB when each layer's pool
+    was sliced out of the stack, written back, and the stack relaid out
+    for the copy-on-write page scatter)."""
+    tok = SyntheticReactionDataset(1, seed=0, direction="retro").tokenizer
+    cfg = with_vocab(retro_config(), tok.vocab_size)
+    params = jax.eval_shape(lambda: s2s.init(jax.random.PRNGKey(0), cfg))
+    eng = StreamingEngine(params, cfg, tok, CELL)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    compiled = eng._megastep_fn.lower(
+        on_chip(eng.params), on_chip(eng.scheduler.state)).compile()
+    pool = eng.scheduler.state.cache["self"].k_pool
+    assert pool.shape[1:] == (eng._paged_geometry()[0], PAGE,
+                              cfg.n_kv_heads * cfg.head_dim)
+    moved = _buffers_moved(
+        compiled.as_text(),
+        {"copy", "copy-start", "dynamic-slice", "dynamic-update-slice"},
+        {pool.size // pool.shape[0], pool.size})
+    assert not moved, "whole-pool moves in the megastep:\n" + "\n".join(moved)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
