@@ -1,8 +1,10 @@
 """Finds everything by name: a cell's entry in ``BENCHMARK.json``, its
 configuration file, its traffic mix (``traffic/<name>.json``), its limits
-(``checks/<cell>.json``) and each metric's reader
-(``metrics/<metric>.py``). Adding a configuration, a mix, a cell or a
-metric is adding files; nothing here changes.
+(``checks/<cell>.json``), each metric's reader (``metrics/<metric>.py``),
+the model code of the configuration's family
+(``bench/families/<family>.py``) and the serving front the mix names
+(``bench/fronts/<front>.py``). Adding a configuration, a family, a front,
+a mix, a cell or a metric is adding files; nothing here changes.
 """
 
 from __future__ import annotations
@@ -11,6 +13,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -69,14 +72,40 @@ def load(root: str, name: str, bench_dir: str = HERE) -> Cell:
         metrics=e2e + layer)
 
 
+def _module(kind: str, name: str, bench_dir: str):
+    """The module ``<bench_dir>/<kind>/<name>.py``, loaded once a process."""
+    path = os.path.join(bench_dir, kind, name + ".py")
+    if not os.path.isfile(path):
+        raise NotFound(f"no {kind} module {path}")
+    mod_name = "bench_" + "".join(ch if ch.isalnum() else "_"
+                                  for ch in f"{kind}_{name}")
+    mod = sys.modules.get(mod_name)
+    if mod is None or getattr(mod, "__file__", None) != path:
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[mod_name]
+            raise
+    return mod
+
+
 def reader(metric: str, bench_dir: str = HERE):
     """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
-    path = os.path.join(bench_dir, "metrics", metric + ".py")
-    if not os.path.isfile(path):
-        raise NotFound(f"no reader {path}")
-    mod_name = "bench_metric_" + "".join(
-        ch if ch.isalnum() else "_" for ch in metric)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module("metrics", metric, bench_dir).read
+
+
+def family(cell: Cell, bench_dir: str = HERE):
+    """The model code of the cell's configuration: its ``family`` key,
+    ``seq2seq`` where the file has none."""
+    return _module(os.path.join("bench", "families"),
+                   cell.config.get("family", "seq2seq"), bench_dir)
+
+
+def front(cell: Cell, bench_dir: str = HERE):
+    """The serving front the cell's mix names: its ``front`` key,
+    ``engine`` (the in-process pump) where the mix has none."""
+    return _module(os.path.join("bench", "fronts"),
+                   cell.traffic.get("front", "engine"), bench_dir)

@@ -27,8 +27,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import run as R  # noqa: E402
-from bench import cells, stats, traffic  # noqa: E402
-from bench import train as training  # noqa: E402
+from bench import cells, engines, stats, traffic  # noqa: E402
 
 
 def ints(s):
@@ -60,7 +59,7 @@ def top1(cell, eng, src, tgt, idx) -> dict:
             "serve_s": wall, "n": len(idx)}
 
 
-def cmd_train(a, cell, out):
+def cmd_train(a, cell, fam, out):
     import jax
 
     e = cell.config["engine"]
@@ -68,16 +67,14 @@ def cmd_train(a, cell, out):
                             e["max_src"], e["max_new"])
     idx = traffic.order(len(src), a.seed)[:a.n]
     # one chunk first: the timings below are of training, not compiling
-    training.train(R.model_cfg(cell), cell.config["train"], a.seed,
-                   steps=cell.config["train"]["chunk"])
+    fam.train(cell, a.seed, steps=cell.config["train"]["chunk"])
     for steps in ints(a.steps):
-        tcfg = dict(cell.config["train"], steps=steps)
         t = time.perf_counter()
-        params, losses = training.train(R.model_cfg(cell), tcfg, a.seed)
+        params, losses = fam.train(cell, a.seed, steps=steps)
         jax.block_until_ready(params)
         train_s = time.perf_counter() - t
-        eng = R.make_engine(cell, params)
-        R.warm(eng, cell, src, idx, R.slots(cell))
+        eng = engines.make_engine(cell, fam, params)
+        engines.warm(eng, cell, src, idx, engines.slots(cell))
         row = {"steps": steps, "train_s": train_s,
                "loss_last100": float(losses[-100:].mean()),
                **top1(cell, eng, src, tgt, idx)}
@@ -86,35 +83,37 @@ def cmd_train(a, cell, out):
         gc.collect()
 
 
-def cmd_slots(a, cell, out):
+def cmd_slots(a, cell, fam, out):
     import jax
 
-    params, _ = training.train(R.model_cfg(cell), cell.config["train"], a.seed)
-    src, order = R.queries(cell, a.seed)
+    params, _ = fam.train(cell, a.seed)
+    src, order = R.queries(cell, fam, a.seed)
     for n in ints(a.slots):
-        eng = R.make_engine(cell, params, n)
         t = time.perf_counter()
-        R.warm(eng, cell, src, order, n)
-        warm_s = time.perf_counter() - t
-        rec = R.window(eng, cell, src, order, a.seed, a.seconds, n)
+        front = cells.front(cell).Front(cell, fam, params, src, order,
+                                        jax.devices()[:1], n_slots=n)
+        build_s = time.perf_counter() - t
+        rec = front.window(src, order, a.seed, a.seconds)
         ms = jax.devices()[0].memory_stats() or {}
         done = stats.finished_in_window(Ctx(rec))
-        out({"n_slots": n, "warm_s": warm_s,
+        out({"n_slots": n, "build_warm_s": build_s,
              "queries_per_s": len(done) / a.seconds,
              "iteration_ms": 1e3 * a.seconds / max(rec.iterations, 1),
              "tokens_per_call": stats.tokens_per_call(Ctx(rec)),
              "peak_bytes_in_use": ms.get("peak_bytes_in_use"),
              "bytes_in_use": ms.get("bytes_in_use")})
-        del eng, rec
+        front.close()
+        del front, rec
         gc.collect()
 
 
-def cmd_control(a, cell, out):
+def cmd_control(a, cell, fam, out):
     import jax
 
+    chips = int(cell.workload["chips"])
     for seed in ints(a.seeds):
         t = time.perf_counter()
-        res = R.run(cell, seed, a.seconds, False, jax.devices()[:1],
+        res = R.run(cell, seed, a.seconds, False, jax.devices()[:chips],
                     control=True)
         out({"seed": seed, "wall_s": time.perf_counter() - t,
              "correct": res["correct"], "attempted": res["attempted"],
@@ -148,7 +147,7 @@ def main(argv=None):
         print(json.dumps(row), flush=True)
 
     {"train": cmd_train, "slots": cmd_slots,
-     "control": cmd_control}[a.cmd](a, cell, out)
+     "control": cmd_control}[a.cmd](a, cell, cells.family(cell), out)
 
 
 if __name__ == "__main__":

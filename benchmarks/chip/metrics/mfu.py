@@ -1,9 +1,11 @@
 """Model step: FLOPs of the plain algorithm for the queries finished in
 the window (``bench.flops``), per second of window, as a share of the
-chip's bf16 peak (``bench/peaks.json``)."""
+bf16 peak of the cell's chips (``bench/peaks.json`` times the device
+count)."""
 
 
 def read(ctx):
     if not ctx.flops:
         return None
-    return 100.0 * ctx.flops / ctx.rec.seconds / ctx.peak["bf16_flops_per_s"]
+    peak = ctx.peak["bf16_flops_per_s"] * ctx.device["count"]
+    return 100.0 * ctx.flops / ctx.rec.seconds / peak

@@ -1,5 +1,6 @@
-"""Device: peak device memory in use by the end of the window
-(``memory_stats()["peak_bytes_in_use"]``), in GB."""
+"""Device: peak device memory in use by the end of the window on the
+fullest of the cell's chips (``memory_stats()["peak_bytes_in_use"]``),
+in GB."""
 
 
 def read(ctx):

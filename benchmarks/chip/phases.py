@@ -5,7 +5,8 @@ run; it runs in one process on the chip.
     python3 benchmarks/chip/phases.py --workload mt-retro.sbs.batch \
         --seed 7 --seconds 20
 
-Set-up and window as ``run.py --trace 1`` has them. After the window,
+Set-up and window as ``run.py --trace 1`` has them, for a cell of the
+in-process front (``bench/fronts/engine.py``). After the window,
 with the engine still alive, it reads what the result line of a run
 cannot: the megastep's device time by named scope (the trace joined to
 ``StreamingEngine.op_scopes()`` by ``bench.scopes``), the device's idle
@@ -29,7 +30,6 @@ sys.path.insert(0, HERE)
 
 import run as R  # noqa: E402
 from bench import cells, scopes, trace  # noqa: E402
-from bench import train as training  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -46,18 +46,18 @@ def main(argv=None) -> int:
         print("phases.py: JAX found no TPU", file=sys.stderr)
         return 2
     R.cache_settings(jax)
-    params, _ = training.train(R.model_cfg(cell), cell.config["train"],
-                               a.seed)
-    n_slots = R.slots(cell)
-    src, order = R.queries(cell, a.seed)
-    eng = R.make_engine(cell, params)
-    R.warm(eng, cell, src, order, n_slots)
+    fam = cells.family(cell)
+    params, _ = fam.train(cell, a.seed)
+    src, order = R.queries(cell, fam, a.seed)
+    front = cells.front(cell).Front(cell, fam, params, src, order,
+                                    jax.devices()[:1])
+    eng = front.eng
     s0 = eng.loop_stats()
     tdir = tempfile.mkdtemp(prefix="chipbench-phases-")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(tdir, profiler_options=opts)
-    rec = R.window(eng, cell, src, order, a.seed, a.seconds, n_slots)
+    rec = front.window(src, order, a.seed, a.seconds)
     jax.profiler.stop_trace()
     s1 = eng.loop_stats()
     op_scopes = eng.op_scopes()

@@ -5,17 +5,21 @@
         --seed 7 --seconds 30 --trace 0
 
 Set-up (counted in ``setup_s``): JAX start-up, weights trained on the
-device from ``--seed``, one paged ``StreamingEngine`` built and its
-programs warmed on the cell's own shapes. The window: the engine's pump
-(``serve_steps(realtime=True)``) driven for ``--seconds`` under the
-cell's traffic mix. After it: device memory is read, the engine freed,
-and a sample of the served requests compared with the plain reference.
+device from ``--seed``, the serving front built and its programs warmed
+on the cell's own shapes. The configuration's ``family`` key names its
+model code (``bench/families/<family>.py``, ``seq2seq`` where absent);
+the mix's ``front`` key names the front (``bench/fronts/<front>.py``,
+``engine``, the in-process pump, where absent). The window: the front serves the cell's traffic
+mix for ``--seconds``. After it: device memory is read on every chip of
+the cell, the front freed, and a sample of the served requests compared
+with the plain reference.
 The last line of stdout is one JSON object; with ``--trace 0`` its
 metrics are the cell's end-to-end metrics, with ``--trace 1`` (window
 traced by the profiler) its per-layer metrics.
 
-Exits 2 with no result line when the cell or the program cannot be found,
-when JAX finds no TPU, or fewer chips than the cell asks for.
+Exits 2 with no result line when the cell, its family or front, or the
+program cannot be found, when JAX finds no TPU, or fewer chips than the
+cell asks for.
 """
 
 from __future__ import annotations
@@ -27,7 +31,6 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import itertools  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -38,11 +41,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, HERE)
 
-from bench import cells, check, chem, drive, flops, trace, traffic  # noqa: E402
-from bench import train as training  # noqa: E402
+from bench import cells, drive, flops, scopes, trace, traffic  # noqa: E402
 
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
-DRAIN_S = 60.0
 
 
 def cache_settings(jax) -> None:
@@ -90,98 +91,18 @@ class Compiles:
             self.n += 1
 
 
-def model_cfg(cell: cells.Cell) -> dict:
-    c = cell.config
-    return {k: c[k] for k in ("d_model", "n_heads", "d_ff", "vocab_size",
-                              "n_encoder_layers", "n_decoder_layers",
-                              "direction")}
-
-
-def program_cfg(cell: cells.Cell):
-    """The program's ``ModelConfig`` for the configuration file."""
-    from repro.configs.base import ModelConfig
-
-    c = cell.config
-    return ModelConfig(
-        name=cell.config_entry["name"], family="seq2seq",
-        n_layers=c["n_decoder_layers"], n_encoder_layers=c["n_encoder_layers"],
-        d_model=c["d_model"], n_heads=c["n_heads"], n_kv_heads=c["n_heads"],
-        d_ff=c["d_ff"], vocab_size=c["vocab_size"], use_bias=True,
-        norm="layernorm", gated_ffn=False, pos="sinusoidal",
-        max_len=c["max_positions"])
-
-
-def slots(cell: cells.Cell) -> int:
-    """The configuration's slot count for the mix's mode."""
-    return int(cell.config["engine"]["n_slots"][cell.traffic["mode"]])
-
-
-def make_engine(cell: cells.Cell, params, n_slots: int | None = None):
-    from repro.serving import EngineConfig, StreamingEngine
-
-    e = cell.config["engine"]
-    ecfg = EngineConfig(
-        mode=cell.traffic["mode"], n_slots=n_slots or slots(cell),
-        n_beams=e["n_beams"], n_drafts=e["n_drafts"],
-        draft_len=e["draft_len"], max_new=e["max_new"],
-        max_src=e["max_src"], paged=True, page_size=e["page_size"])
-    return StreamingEngine(params, program_cfg(cell), chem.Tokenizer(), ecfg)
-
-
-def queries(cell: cells.Cell, seed: int):
-    """(pool src array, the seed's order) for the cell's mix."""
-    e = cell.config["engine"]
-    src, _ = traffic.pool(cell.config["direction"], cell.traffic,
-                          e["max_src"], e["max_new"])
+def queries(cell: cells.Cell, fam, seed: int):
+    """(the family's query pool for the cell's mix, the seed's order)."""
+    src = fam.pool(cell)
     return src, traffic.order(len(src), seed)
 
 
-def warm(eng, cell: cells.Cell, src, order, n_slots: int) -> None:
-    """Serve ``n_slots`` + 1 requests from the end of the seed's order to
-    completion through the same pump: every program the window runs
-    (admission, megastep, release, read-out) is compiled or loaded here."""
-    for i in order[-(n_slots + 1):]:
-        eng.submit(src[i], mode=cell.traffic["mode"])
-    eng.serve(realtime=True)
-
-
-def window(eng, cell, src, order, seed: int, seconds: float, n_slots: int):
-    mix = cell.traffic
-    drain = float(mix.get("drain_s", DRAIN_S))
-    if mix["load"] == "backlog":
-        feed = (src[i] for i in itertools.cycle(order))
-        depth = n_slots * (1 + int(mix["backlog_slots"]))
-        rec = drive.backlog(eng, feed, mix["mode"], seconds, depth, drain)
-    else:
-        due = traffic.due_times(float(mix["rate_per_s"]), seconds, seed)
-        feed = (src[order[i % len(order)]] for i in range(len(due)))
-        rec = drive.open_loop(eng, feed, due, mix["mode"], seconds, drain)
-    return rec
-
-
-def window_flops(cell, rec) -> float:
-    cfg = model_cfg(cell)
+def window_flops(cell, fam, rec) -> float:
     total = 0.0
     for rid, (t, r) in rec.seen.items():
         if t <= rec.seconds and rec.finished(r):
-            S = int((rec.queries[rid] != 0).sum())
-            total += flops.query(cfg, S, [int(x) for x in r.lengths])
+            total += fam.query_flops(cell, rec.queries[rid], r)
     return total
-
-
-def check_numbers(cell, params, rec, seed: int, control: bool = False):
-    """The compared numbers of this run (and the control's, if asked)."""
-    picked = [(rec.queries[rid], r) for rid, (t, r) in sorted(rec.seen.items())
-              if rec.finished(r) and rid in rec.queries]
-    picked = check.sample(picked, seed)
-    beam = cell.traffic["mode"] in ("beam", "speculative_beam")
-    out = {"unfinished": float(len(rec.failed()))}
-    if not picked:
-        out["finished"] = 0.0
-        return out
-    out.update(check.numbers(params, model_cfg(cell), picked, beam,
-                             cell.config["engine"]["max_new"], control))
-    return out
 
 
 def verdict(numbers: dict, limits: dict) -> tuple[bool, dict]:
@@ -201,9 +122,10 @@ def main(argv=None) -> int:
 
     try:
         cell = cells.load(ROOT, args.workload)
+        traffic.validate(cell.traffic)
+        cells.family(cell), cells.front(cell)
     except (cells.NotFound, KeyError, ValueError) as e:
         refuse(f"cannot load cell {args.workload!r}: {e}")
-    traffic.validate(cell.traffic)
     src_dir = os.path.join(ROOT, "src")
     if not os.path.isdir(os.path.join(src_dir, "repro")):
         refuse(f"the program is not at {src_dir}")
@@ -230,13 +152,11 @@ def run(cell: cells.Cell, seed: int, seconds: float, traced: bool,
 
     compiles = Compiles(jax)
     # ---- set-up
-    params, losses = training.train(model_cfg(cell), cell.config["train"],
-                                    seed)
-    n_slots = slots(cell)
-    src, order = queries(cell, seed)
-    eng = make_engine(cell, params)
-    warm(eng, cell, src, order, n_slots)
-    stats0 = eng.loop_stats()
+    fam = cells.family(cell)
+    params, losses = fam.train(cell, seed)
+    src, order = queries(cell, fam, seed)
+    front = cells.front(cell).Front(cell, fam, params, src, order, devs)
+    c0 = front.counters()
     n_compiles0 = compiles.n
     tdir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced else None
     if tdir:
@@ -248,36 +168,43 @@ def run(cell: cells.Cell, seed: int, seconds: float, traced: bool,
     setup_s = time.perf_counter() - T_START
 
     # ---- the window
-    rec = window(eng, cell, src, order, seed, seconds, n_slots)
+    rec = front.window(src, order, seed, seconds)
     if tdir:
         jax.profiler.stop_trace()
     in_window_compiles = compiles.n - n_compiles0
-    stats1 = eng.loop_stats()
-    peak_bytes = int((devs[0].memory_stats() or {}).get("peak_bytes_in_use",
-                                                        0))
+    c1 = front.counters()
+    peak_bytes = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                     for d in devs)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": peak_bytes}
     red = None
     if tdir:
-        red = trace.reduce(trace.find_xplane(tdir))
+        path = trace.find_xplane(tdir)
+        red = trace.reduce(path)
+        op_scopes = front.op_scopes()
+        if op_scopes is not None:
+            prog = scopes.program_ops(path)
+            red["scopes"] = dict(scopes.by_scope(prog["ops"], op_scopes),
+                                 runs=prog["runs"])
         shutil.rmtree(tdir, ignore_errors=True)
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
-    loop = {"dispatches": stats1["n_dispatches"] - stats0["n_dispatches"],
-            "iterations": rec.iterations}
+    loop = {k: v - c0[k] for k, v in c1.items()}
+    loop["iterations"] = rec.iterations
     ctx = Context(cell=cell, rec=rec, setup_s=setup_s, loop=loop,
                   flops=0.0, peak=flops.peak(devs[0].device_kind),
                   device=device, trace=red)
-    del eng
+    front.close()
+    del front
     gc.collect()
-    return finish(cell, ctx, params, seed, losses, in_window_compiles,
+    return finish(cell, fam, ctx, params, seed, losses, in_window_compiles,
                   control)
 
 
-def finish(cell, ctx, params, seed, losses, in_window_compiles,
+def finish(cell, fam, ctx, params, seed, losses, in_window_compiles,
            control=False) -> dict:
     """Metrics, the check, the stderr lines; returns the result line."""
     rec, traced = ctx.rec, ctx.trace is not None
-    ctx.flops = window_flops(cell, rec)
+    ctx.flops = window_flops(cell, fam, rec)
     kind = "per_layer" if traced else "end_to_end"
     metrics = {}
     for m in cell.metrics:
@@ -286,7 +213,7 @@ def finish(cell, ctx, params, seed, losses, in_window_compiles,
         v = cells.reader(m["name"])(ctx)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-    numbers = check_numbers(cell, params, rec, seed, control)
+    numbers = fam.check_numbers(cell, params, rec, seed, control)
     ok, shown = verdict(numbers, cell.limits)
     attempted = rec.attempted()
     failed = rec.failed()

@@ -24,11 +24,11 @@ import pytest  # noqa: E402
 def tiny_weights():
     """The tiny configuration trained once, for every test that runs it:
     trained well enough to end its answers and accept drafts."""
-    import run
     import tiny
-    from bench import train
+    from bench import cells
 
-    return train.train(run.model_cfg(tiny.cell()), tiny.CONFIG["train"], 5)
+    cell = tiny.cell()
+    return cells.family(cell).train(cell, 5)
 
 
 @pytest.fixture

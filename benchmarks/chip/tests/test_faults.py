@@ -9,7 +9,7 @@ import pytest
 
 import run as R
 import tiny
-from bench import chem, flops
+from bench import cells, chem, flops
 
 
 @pytest.fixture(autouse=True)
@@ -17,16 +17,19 @@ def cpu_peak(monkeypatch):
     monkeypatch.setattr(flops, "peak", lambda kind: {"bf16_flops_per_s": 1e12})
 
 
-def unchanged(eng, monkeypatch):
+def unchanged(front, monkeypatch):
     """The step returns its state unchanged (its bundle read from it)."""
+    eng = front.eng
+
     def same(params, g):
         return g, eng._make_bundle(g, eng._slot_counts(g), None)
 
     monkeypatch.setattr(eng, "_megastep_fn", jax.jit(same))
 
 
-def altered(eng, monkeypatch):
+def altered(front, monkeypatch):
     """A token altered where it is produced (the slot's read-out)."""
+    eng = front.eng
     orig = eng._read_slot
 
     def read(state, slot):
@@ -66,16 +69,17 @@ CASES = [
     ids=[f"{m}-{f.__name__ if f else 'sound'}" for m, f, _ in CASES])
 def test_fault_makes_run_incorrect(monkeypatch, trained, mode, fault, early):
     cell = tiny.cell(mode, "backlog", drain_s=2)
-    window = R.window
+    Front = cells.front(cell).Front
+    window = Front.window
 
-    def broken(eng, *a, **k):
-        fault(eng, monkeypatch)
-        return window(eng, *a, **k)
+    def broken(self, *a, **k):
+        fault(self, monkeypatch)
+        return window(self, *a, **k)
 
     if fault is not None and early:
         fault(monkeypatch)
     elif fault is not None:
-        monkeypatch.setattr(R, "window", broken)
+        monkeypatch.setattr(Front, "window", broken)
     res = R.run(cell, 2**35 + 11, 2.0, False, jax.devices()[:1])
     assert res["correct"] is (fault is None), res["checks"]
     assert list(res)[-1] == "checks"

@@ -29,6 +29,12 @@ def test_cell_found_by_name(name):
     assert any("layer" in m for m in cell.metrics)
     for n in names:
         assert callable(cells.reader(n))
+    fam, front = cells.family(cell), cells.front(cell)
+    for f in ("model_cfg", "program_cfg", "tokenizer", "train", "pool",
+              "query_flops", "check_numbers"):
+        assert callable(getattr(fam, f)), f
+    for f in ("counters", "window", "op_scopes", "close"):
+        assert callable(getattr(front.Front, f)), f
     assert set(cell.limits) >= {"unfinished"}
     for m in cell.metrics:
         if "layer" in m:
